@@ -118,6 +118,9 @@ def main() -> None:
             ap.error(f"unknown --engine preset {args.engine!r}: expected "
                      f"one of {sorted(PRESETS)}")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     rows = []
     wall = {}
     errors = {}

@@ -170,7 +170,7 @@ class RunConfig:
 
     kernels: str = "xla"              # "pallas" | "xla"
     dtype: str = "bfloat16"           # compute dtype
-    param_dtype: str = "float32"
+    param_dtype: str = "float32"      # storage dtype (models.init_lm_params)
     remat: bool = True
     scan_layers: bool = True
     sequence_parallel: bool = True    # SP residual stream sharding

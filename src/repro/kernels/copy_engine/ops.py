@@ -1,7 +1,8 @@
 """jit'd public wrappers for the copy engine, with backend dispatch.
 
-`backend="pallas"` uses the TPU kernel (interpret-mode on CPU);
-`backend="xla"` uses the jnp oracle — semantically identical (tested).
+`backend="pallas"` (the default) compiles the TPU kernel; `interpret=True`
+runs it in Pallas interpret mode instead.  `backend="xla"` uses the jnp
+oracle — semantically identical (tested).
 """
 
 from __future__ import annotations
@@ -12,27 +13,22 @@ from typing import Callable, Optional
 import jax
 
 from . import copy_engine, ref
-from repro.kernels.runtime import default_backend, resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("transform", "out_dtype",
                                              "backend", "interpret"))
 def copy_2d(x: jax.Array, transform: Optional[Callable] = None,
-            out_dtype=None, backend: Optional[str] = None,
-            interpret: Optional[bool] = None) -> jax.Array:
-    backend = backend or default_backend()
+            out_dtype=None, backend: str = "pallas",
+            interpret: bool = False) -> jax.Array:
     if backend == "xla":
         return ref.copy_2d_ref(x, transform, out_dtype)
     return copy_engine.copy_2d_pallas(
-        x, transform=transform, out_dtype=out_dtype,
-        interpret=resolve_interpret(interpret))
+        x, transform=transform, out_dtype=out_dtype, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "interpret"))
-def strided_copy_nd(x: jax.Array, backend: Optional[str] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    backend = backend or default_backend()
+def strided_copy_nd(x: jax.Array, backend: str = "pallas",
+                    interpret: bool = False) -> jax.Array:
     if backend == "xla":
         return ref.strided_copy_nd_ref(x)
-    return copy_engine.strided_copy_nd_pallas(
-        x, interpret=resolve_interpret(interpret))
+    return copy_engine.strided_copy_nd_pallas(x, interpret=interpret)

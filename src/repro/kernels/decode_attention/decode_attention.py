@@ -27,11 +27,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BK = 1024
 NEG_INF = -1e30
@@ -40,7 +36,7 @@ NEG_INF = -1e30
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *,
                    scale: float, window: int, softcap: float,
-                   bk: int, n_k: int, G: int):
+                   bk: int, n_k: int, G: int, seq_k: int):
     ik = pl.program_id(2)
     kv_len = len_ref[0, 0]
 
@@ -60,6 +56,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         q = q_ref[0, 0].astype(jnp.float32)          # (G, D)
         k = k_ref[0, 0].astype(jnp.float32)          # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)          # (bk, D)
+        if seq_k % bk:
+            # the last tile runs past the cache: its rows beyond seq_k
+            # hold whatever the VMEM buffer held, and 0 * NaN is NaN
+            rows = k_start + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            v = jnp.where(rows < seq_k, v, 0.0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap > 0:
@@ -109,12 +110,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, softcap=softcap,
-        bk=bk, n_k=grid[2], G=G)
-
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+        bk=bk, n_k=grid[2], G=G, seq_k=S)
 
     out = pl.pallas_call(
         kernel,
@@ -127,14 +123,11 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[_vmem((G, 1)), _vmem((G, 1)), _vmem((G, D))],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, 1), jnp.float32),
+                        pltpu.VMEM((G, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(len_arr, qr, k, v)
     return out.reshape(B, Hq, D)
-
-
-def _vmem(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    raise RuntimeError("Pallas TPU extensions unavailable")  # pragma: no cover

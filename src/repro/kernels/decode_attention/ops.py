@@ -8,7 +8,6 @@ from typing import Optional, Union
 import jax
 
 from . import decode_attention as da, ref
-from repro.kernels.runtime import default_backend, resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -18,13 +17,12 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      window: int = 0, softcap: float = 0.0,
                      scale: Optional[float] = None,
                      block_k: int = da.DEFAULT_BK,
-                     backend: Optional[str] = None,
-                     interpret: Optional[bool] = None) -> jax.Array:
-    backend = backend or default_backend()
+                     backend: str = "pallas",
+                     interpret: bool = False) -> jax.Array:
     if backend == "xla":
         return ref.decode_attention_ref(q, k, v, kv_len=kv_len,
                                         window=window, softcap=softcap,
                                         scale=scale)
     return da.decode_attention_pallas(
         q, k, v, kv_len=kv_len, window=window, softcap=softcap, scale=scale,
-        block_k=block_k, interpret=resolve_interpret(interpret))
+        block_k=block_k, interpret=interpret)

@@ -31,11 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -70,6 +66,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[0].astype(jnp.float32)         # (bq, d)
         k = k_ref[0].astype(jnp.float32)         # (bk, d)
         v = v_ref[0].astype(jnp.float32)         # (bk, d)
+        if seq_k % bk:
+            # rows past seq_k in the last kv tile are stale VMEM: zero
+            # them so that p == 0 there cannot meet a NaN
+            kv_rows = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0)
+            v = jnp.where(kv_rows < seq_k, v, 0.0)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -128,11 +130,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, bq=bq, bk=bk, n_k=grid[2], seq_k=Sk)
 
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -144,15 +141,12 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         out_specs=pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq, D), q.dtype),
         scratch_shapes=[
-            _vmem((bq, 1)), _vmem((bq, 1)), _vmem((bq, D)),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=compiler_params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(B, Hq, Sq, D)
-
-
-def _vmem(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    raise RuntimeError("Pallas TPU extensions unavailable")  # pragma: no cover

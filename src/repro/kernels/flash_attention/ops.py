@@ -8,7 +8,6 @@ from typing import Optional
 import jax
 
 from . import flash_attention as fa, ref
-from repro.kernels.runtime import default_backend, resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -18,13 +17,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: int = 0,
                     softcap: float = 0.0, scale: Optional[float] = None,
                     block_q: int = fa.DEFAULT_BQ, block_k: int = fa.DEFAULT_BK,
-                    backend: Optional[str] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
-    backend = backend or default_backend()
+                    backend: str = "pallas",
+                    interpret: bool = False) -> jax.Array:
     if backend == "xla":
         return ref.attention_ref(q, k, v, causal=causal, window=window,
                                  softcap=softcap, scale=scale)
     return fa.flash_attention_pallas(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
         block_q=block_q, block_k=block_k,
-        interpret=resolve_interpret(interpret))
+        interpret=interpret)

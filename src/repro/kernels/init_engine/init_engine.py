@@ -53,13 +53,13 @@ def _prng_kernel(o_ref, *, seed, cols_total, tile):
     bits = splitmix32(ctr + jnp.uint32(seed))
     if o_ref.dtype == jnp.uint32:
         o_ref[...] = bits
-    elif o_ref.dtype == jnp.float32:
-        # uniform [0, 1): use the top 24 bits
-        o_ref[...] = (bits >> jnp.uint32(8)).astype(jnp.float32) / \
-            jnp.float32(1 << 24)
-    elif o_ref.dtype == jnp.bfloat16:
-        o_ref[...] = ((bits >> jnp.uint32(8)).astype(jnp.float32) /
-                      jnp.float32(1 << 24)).astype(jnp.bfloat16)
+    elif o_ref.dtype in (jnp.float32, jnp.bfloat16):
+        # uniform [0, 1) from the top 24 bits.  They fit int32, so the
+        # float conversion starts from int32: Mosaic has no uint32 ->
+        # float cast.
+        top = jax.lax.bitcast_convert_type(bits >> jnp.uint32(8), jnp.int32)
+        o_ref[...] = (top.astype(jnp.float32) /
+                      jnp.float32(1 << 24)).astype(o_ref.dtype)
     elif o_ref.dtype == jnp.int8:
         o_ref[...] = (bits & jnp.uint32(0xFF)).astype(jnp.uint8) \
             .view(jnp.int8).reshape(o_ref.shape)

@@ -22,11 +22,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-only helpers; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BM = 256
@@ -35,15 +31,24 @@ DEFAULT_BN = 256
 
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *,
-                   n_k: int, epilogue: Optional[Callable]):
+                   n_k: int, k_tail: int, epilogue: Optional[Callable]):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                            preferred_element_type=jnp.float32)
+    x = x_ref[...]
+    w = w_ref[...]
+    if k_tail:
+        # the last k block runs past the contraction; its stale VMEM
+        # columns of x and rows of w would be summed in: zero them
+        valid = jnp.where(k == n_k - 1, k_tail, x.shape[1])
+        x = jnp.where(jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+                      < valid, x, jnp.zeros_like(x))
+        w = jnp.where(jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+                      < valid, w, jnp.zeros_like(w))
+    acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _retire():
@@ -60,9 +65,9 @@ def matmul_pallas(x: jax.Array, w: jax.Array,
                   interpret: bool = False) -> jax.Array:
     """x @ w with (bm, bk, bn) VMEM tiles and fp32 accumulation.
 
-    Shapes: x (M, K), w (K, N) → (M, N).  M/K/N need not divide the block —
-    Pallas masks the ragged edges (the legalizer pads, like the RTL pads
-    narrow bursts to bus beats).
+    Shapes: x (M, K), w (K, N) → (M, N).  M/K/N need not divide the block:
+    rows and columns past M and N are dropped when the output block is
+    written, and the kernel zeroes the contraction tail past K.
     """
     M, K = x.shape
     K2, N = w.shape
@@ -73,17 +78,13 @@ def matmul_pallas(x: jax.Array, w: jax.Array,
     grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), pl.cdiv(K, bk))
     out_dtype = out_dtype or x.dtype
 
-    kernel = functools.partial(_matmul_kernel, n_k=grid[2],
+    kernel = functools.partial(_matmul_kernel, n_k=grid[2], k_tail=K % bk,
                                epilogue=epilogue)
     flops = 2 * M * N * K
     bytes_accessed = (M * K * x.dtype.itemsize + K * N * w.dtype.itemsize +
                       M * N * jnp.dtype(out_dtype).itemsize)
     cost = pl.CostEstimate(flops=flops, bytes_accessed=bytes_accessed,
                            transcendentals=0)
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -93,14 +94,9 @@ def matmul_pallas(x: jax.Array, w: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[_scratch((bm, bn))],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=cost,
         interpret=interpret,
     )(x, w)
-
-
-def _scratch(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    raise RuntimeError("Pallas TPU extensions unavailable")  # pragma: no cover

@@ -8,7 +8,6 @@ from typing import Callable, Optional, Tuple
 import jax
 
 from . import matmul_dma, ref
-from repro.kernels.runtime import default_backend, resolve_interpret
 
 
 @functools.partial(jax.jit, static_argnames=("block", "out_dtype",
@@ -17,11 +16,10 @@ from repro.kernels.runtime import default_backend, resolve_interpret
 def matmul(x: jax.Array, w: jax.Array,
            block: Optional[Tuple[int, int, int]] = None,
            out_dtype=None, epilogue: Optional[Callable] = None,
-           backend: Optional[str] = None,
-           interpret: Optional[bool] = None) -> jax.Array:
-    backend = backend or default_backend()
+           backend: str = "pallas",
+           interpret: bool = False) -> jax.Array:
     if backend == "xla":
         return ref.matmul_ref(x, w, out_dtype, epilogue)
     return matmul_dma.matmul_pallas(
         x, w, block=block, out_dtype=out_dtype, epilogue=epilogue,
-        interpret=resolve_interpret(interpret))
+        interpret=interpret)

@@ -1,34 +1,20 @@
-"""Kernel runtime knobs shared by all kernel wrappers.
+"""Where the kernels run.
 
-On a real TPU, `default_backend()` is "pallas" with `interpret=False`.
-In this CPU container the kernels still run — in Pallas interpret mode —
-so tests sweep shapes/dtypes against the refs; the distributed dry-run
-path selects "xla" explicitly (Pallas cannot lower on the CPU SPMD
-placeholder backend).
+Every kernel wrapper (`<kernel>/ops.py`) defaults to the compiled Pallas
+path: ``backend="pallas"``, ``interpret=False``.  Nothing looks at the
+device to pick another path.  The pure-jnp reference (``backend="xla"``)
+and Pallas interpret mode (``interpret=True``) run only where a caller
+asks for them, as the CPU tests and the dry-run do.  Off a TPU the
+compiled path fails when Mosaic lowers it, loudly, instead of quietly
+interpreting.
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:  # pragma: no cover - uninitialized backend
-        return False
-
-
-def default_backend() -> str:
-    env = os.environ.get("REPRO_KERNEL_BACKEND")
-    if env in ("pallas", "xla"):
-        return env
-    return "pallas" if on_tpu() else "xla"
-
-
-def resolve_interpret(interpret=None) -> bool:
-    if interpret is not None:
-        return interpret
-    return not on_tpu()
+    """True when JAX's default device is a TPU.  A backend that fails to
+    initialise raises; it is not read as "no TPU"."""
+    return jax.devices()[0].platform == "tpu"

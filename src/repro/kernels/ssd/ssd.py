@@ -16,6 +16,13 @@ chunk.
 Layouts (P = headdim, N = state dim, G = B/C groups):
   x (B, H, S, P) · dt (B, H, S) · A (H,) · D (H,) · B/C (B, G, S, N)
 Grid: (B, H, S/L) — chunks sequential innermost.
+
+Mosaic tiles the last two dims of every block by (8, 128), and has no
+cumsum.  So the wrapper takes the per-chunk cumulative decay
+``cum = cumsum(A·dt)`` in XLA, and hands ``dt`` and ``cum`` to the
+kernel twice each: as (B, H, 1, S) rows and (B, H, S, 1) columns, whose
+(1, L) and (L, 1) blocks are legal.  ``A`` and ``D`` are per-head
+scalars in SMEM.
 """
 
 from __future__ import annotations
@@ -25,17 +32,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_CHUNK = 128
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, y_ref,
-                state_out_ref, state_ref, *, L: int, n_chunks: int):
+def _ssd_kernel(x_ref, dtr_ref, dtc_ref, cumr_ref, cumc_ref, a_ref, d_ref,
+                b_ref, c_ref, y_ref, state_out_ref, state_ref, *, L: int,
+                n_chunks: int):
+    h = pl.program_id(1)
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
@@ -43,37 +48,36 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, d_ref, b_ref, c_ref, y_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)           # (L, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)         # (L,)
-    a = a_ref[0, 0].astype(jnp.float32)           # scalar (negative)
-    dsk = d_ref[0, 0].astype(jnp.float32)         # scalar skip
+    dt_row = dtr_ref[0, 0]                        # (1, L)
+    dt_col = dtc_ref[0, 0]                        # (L, 1)
+    cum_row = cumr_ref[0, 0]                      # (1, L)  inclusive
+    cum_col = cumc_ref[0, 0]                      # (L, 1)
+    a = a_ref[h]                                  # scalar (negative)
+    dsk = d_ref[h]                                # scalar skip
     bb = b_ref[0, 0].astype(jnp.float32)          # (L, N)
     cc = c_ref[0, 0].astype(jnp.float32)          # (L, N)
-
-    adt = a * dt                                  # (L,)
-    cum = jnp.cumsum(adt)                         # (L,)  inclusive
-    total = cum[-1]
+    total = jnp.sum(a * dt_row, axis=1, keepdims=True)    # (1, 1)
 
     # intra-chunk: scores[t, s] = (C_t·B_s) * exp(cum_t - cum_s) * dt_s, s<=t
-    seg = cum[:, None] - cum[None, :]             # (L, L)
     mask = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
-    decay = jnp.where(mask, jnp.exp(seg), 0.0)
+    decay = jnp.where(mask, jnp.exp(cum_col - cum_row), 0.0)   # (L, L)
     scores = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    scores = scores * decay * dt[None, :]
+    scores = scores * decay * dt_row
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: y_t += exp(cum_t) * C_t @ h_prev
     h_prev = state_ref[...]                       # (N, P)
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_col) * jax.lax.dot_general(
         cc, h_prev, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     # state update: h = exp(total)·h_prev + Σ_s exp(total-cum_s)·dt_s·B_s⊗x_s
-    w = jnp.exp(total - cum) * dt                 # (L,)
+    w = jnp.exp(total - cum_col) * dt_col         # (L, 1)
     state_ref[...] = jnp.exp(total) * h_prev + jax.lax.dot_general(
-        bb * w[:, None], x, (((0,), (0,)), ((), ())),
+        (bb * w).T, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     y_ref[0, 0] = (y + dsk * x).astype(y_ref.dtype)
@@ -100,23 +104,27 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, D: jax.Array,
     n_chunks = S // chunk
     grid = (Bb, H, n_chunks)
 
-    a2 = A.reshape(H, 1)
-    d2 = D.reshape(H, 1)
+    dt = dt.astype(jnp.float32)
+    A = A.astype(jnp.float32)
+    cum = jnp.cumsum((A[None, :, None] * dt).reshape(Bb, H, n_chunks, chunk),
+                     axis=-1).reshape(Bb, H, S)
 
+    def rows(a):
+        return a.reshape(Bb, H, 1, S)
+
+    def cols(a):
+        return a.reshape(Bb, H, S, 1)
+
+    row_spec = pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c))
+    col_spec = pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(_ssd_kernel, L=chunk, n_chunks=n_chunks)
-    compiler_params = None
-    if pltpu is not None and not interpret:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    out = pl.pallas_call(
+    y, state = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
+            row_spec, col_spec, row_spec, col_spec, smem, smem,
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hpg, c, 0)),
             pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h // hpg, c, 0)),
         ],
@@ -128,17 +136,12 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, A: jax.Array, D: jax.Array,
             jax.ShapeDtypeStruct((Bb, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((Bb, H, N, P), jnp.float32),
         ],
-        scratch_shapes=[_vmem((N, P))],
-        compiler_params=compiler_params,
+        scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, a2, d2, B, C)
-    y, state = out
+    )(x, rows(dt), cols(dt), rows(cum), cols(cum), A, D.astype(jnp.float32),
+      B, C)
     if return_state:
         return y, state
     return y
-
-
-def _vmem(shape):
-    if pltpu is not None:
-        return pltpu.VMEM(shape, jnp.float32)
-    raise RuntimeError("Pallas TPU extensions unavailable")  # pragma: no cover

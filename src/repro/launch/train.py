@@ -1,11 +1,13 @@
 """Training driver.
 
-On this CPU container it trains a reduced config end-to-end (the examples
-use it); on a real TPU slice the same driver jits the full config with the
-production-mesh shardings from `specs.build_cell`.
+By default it trains the named config at its published width, with the
+XLA path, bfloat16 compute and rematerialisation (the Pallas kernels
+have no backward pass).  ``--reduced`` trains a tiny same-family config
+in float32, which runs on the CPU.  Full-width gemma2-2b with float32
+AdamW state needs about 42 GB, more than one TPU v5e holds.
 
   PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b --reduced \
-      --steps 50 --seq-len 128 --batch 8 --ckpt /tmp/ckpt
+      --steps 50 --seq-len 128 --batch 8 --ckpt ckpt/
 """
 
 from __future__ import annotations
@@ -14,17 +16,18 @@ import argparse
 import json
 import time
 
-
 from repro.configs import get
 from repro.configs.base import RunConfig, reduced as reduce_cfg
 from repro.train import Trainer, TrainerConfig
 from repro.dist.fault import FaultConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config in float32 (CPU)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--seq-len", type=int, default=128)
     ap.add_argument("--batch", type=int, default=8)
@@ -35,12 +38,15 @@ def main() -> None:
     ap.add_argument("--fault-policy", default="replay",
                     choices=["replay", "continue", "abort"])
     args = ap.parse_args()
+    enable_compile_cache()
 
-    cfg = get(args.arch)
     if args.reduced:
-        cfg = reduce_cfg(cfg)
-    rcfg = RunConfig(kernels="xla", dtype="float32", remat=False,
-                     learning_rate=args.lr)
+        cfg = reduce_cfg(get(args.arch))
+        rcfg = RunConfig(kernels="xla", dtype="float32", remat=False,
+                         learning_rate=args.lr)
+    else:
+        cfg = get(args.arch)
+        rcfg = RunConfig(kernels="xla", learning_rate=args.lr)
     tcfg = TrainerConfig(
         total_steps=args.steps,
         checkpoint_every=args.ckpt_every,

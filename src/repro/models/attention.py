@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig, RunConfig
 from repro.dist.sharding import hint
 from repro.kernels.flash_attention.ops import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from .common import Params, apply_rope, dense, dense_init, fold_keys
@@ -46,8 +45,7 @@ def init_attention(key, cfg: ArchConfig, cross: bool = False) -> Params:
 
 def chunked_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool, window: int, softcap_v: float,
-                  scale: float, chunk_q: int, chunk_k: int,
-                  q_offset: int = 0) -> jax.Array:
+                  scale: float, chunk_q: int, chunk_k: int) -> jax.Array:
     """q (B,Hq,Sq,D), k/v (B,Hkv,Sk,D) → (B,Hq,Sq,D); fp32 softmax."""
     B, Hq, Sq, D = q.shape
     _, Hkv, Sk, _ = k.shape
@@ -75,7 +73,7 @@ def chunked_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     kf = hint("attn_kv5", kf.reshape(B, Hkv, nk, bk, D))
     vf = hint("attn_kv5", vf.reshape(B, Hkv, nk, bk, D))
 
-    rows = q_offset + jnp.arange(Sq_p).reshape(nq, bq)      # absolute q pos
+    rows = jnp.arange(Sq_p).reshape(nq, bq)                 # q positions
 
     def kv_step(carry, inp):
         m, l, acc = carry                                   # (B,Hkv,G,nq,bq[,D])
@@ -112,20 +110,12 @@ def chunked_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     return hint("attn_out", out.astype(q.dtype))
 
 
-def _attend(q, k, v, *, causal, window, softcap_v, scale, rcfg: RunConfig,
-            q_offset: int = 0):
+def _attend(q, k, v, *, causal, window, softcap_v, scale, rcfg: RunConfig):
     if rcfg.kernels == "pallas":
-        if q_offset:
-            # kernels assume aligned prefill; fall back to the oracle
-            return attention_ref(q, k, v, causal=causal, window=window,
-                                 softcap=softcap_v, scale=scale,
-                                 q_offset=q_offset)
         return flash_attention(q, k, v, causal=causal, window=window,
-                               softcap=softcap_v, scale=scale,
-                               backend="pallas")
+                               softcap=softcap_v, scale=scale)
     return chunked_flash(q, k, v, causal, window, softcap_v, scale,
-                         rcfg.attn_chunk_q, rcfg.attn_chunk_k,
-                         q_offset=q_offset)
+                         rcfg.attn_chunk_q, rcfg.attn_chunk_k)
 
 
 # --------------------------------------------------------------------------
@@ -219,7 +209,7 @@ def attention_decode_step(p: Params, x: jax.Array, cache_k: jax.Array,
     if rcfg.kernels == "pallas":
         o = decode_attention(q1, cache_k, cache_v, kv_len=kv_len,
                              window=window, softcap=cfg.attn_softcap,
-                             scale=scale, backend="pallas")
+                             scale=scale)
     else:
         o = decode_attention_ref(q1, cache_k, cache_v, kv_len=kv_len,
                                  window=window, softcap=cfg.attn_softcap,

@@ -10,6 +10,7 @@ PartitionSpecs, so no sharding metadata lives here.
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Any, Dict, Optional
 
 import jax
@@ -121,4 +122,7 @@ def unembed(p: Params, x: jax.Array, compute_dtype=jnp.bfloat16
 
 
 def fold_keys(key, *names: str):
-    return tuple(jax.random.fold_in(key, hash(n) % (2 ** 31)) for n in names)
+    # crc32, not hash(): str hashes change from one process to the next,
+    # and the same seed must give the same weights in every run
+    return tuple(jax.random.fold_in(key, zlib.crc32(n.encode()) % (2 ** 31))
+                 for n in names)

@@ -1,6 +1,7 @@
 """Decoder-only LM assembly (dense / MoE / SSM / hybrid / VLM).
 
   init_lm           — full param tree (eval_shape-compatible)
+  init_lm_params    — init_lm as one program, stored in rcfg.param_dtype
   lm_forward        — tokens (+ optional patch embeddings) → logits, aux
   lm_loss           — next-token cross entropy (sharded-vocab-safe)
   init_decode_cache — per-segment KV/SSM caches
@@ -9,6 +10,7 @@
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -52,6 +54,18 @@ def init_lm(key, cfg: ArchConfig) -> Params:
         p["vision_proj"] = dense_init(kv, cfg.vision.patch_embed_dim,
                                       cfg.d_model)
     return p
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def init_lm_params(key, cfg: ArchConfig, rcfg: RunConfig) -> Params:
+    """`init_lm` compiled as one program whose floating leaves come out in
+    ``rcfg.param_dtype``.  A bfloat16 init never holds the float32 tree
+    on the device next to its copy (gemma2-2b: 10.5 GB in float32)."""
+    dtype = jnp.dtype(rcfg.param_dtype)
+    return jax.tree_util.tree_map(
+        lambda a: a.astype(dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a,
+        init_lm(key, cfg))
 
 
 def _logits(p: Params, x: jax.Array, cfg: ArchConfig,

@@ -1,5 +1,5 @@
-"""`StepLM` — the existing jax prefill/decode step functions bound to
-the continuous-batching front door.
+"""`StepLM` — the model's jitted prefill/decode steps bound to the
+continuous-batching front door.
 
 The dynamic batch is served by *grouping*: requests at the same decode
 position are stacked along the cache batch axis and run through ONE
@@ -32,23 +32,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, RunConfig
-from ..serve_step import make_decode_step, make_prefill_step
+from repro.models import lm_decode_step, lm_prefill
 from .model import HashLM
+
+# compiled once per (config, run config, cache capacity, shapes) and
+# shared by every StepLM: a second server over one model compiles nothing
+_prefill = jax.jit(lm_prefill, static_argnames=("cfg", "rcfg", "max_len"))
+_decode = jax.jit(lm_decode_step, static_argnames=("cfg", "rcfg"))
 
 
 class StepLM:
-    """Model adapter over `make_prefill_step` / `make_decode_step`."""
+    """Model adapter over `lm_prefill` / `lm_decode_step`."""
 
     def __init__(self, cfg: ArchConfig, rcfg: RunConfig, params,
                  max_len: int, row_bytes: int, eos_token: int = -1,
                  seed: int = 0) -> None:
         self.cfg = cfg
+        self.rcfg = rcfg
         self.vocab = cfg.vocab_size
         self.eos_token = eos_token
         self.params = params
         self.max_len = max_len
-        self._prefill = make_prefill_step(cfg, rcfg, max_len=max_len)
-        self._decode = jax.jit(make_decode_step(cfg, rcfg))
         self._mirror = HashLM(row_bytes, vocab=self.vocab,
                               eos_token=eos_token, seed=seed)
         self._key = jax.random.PRNGKey(seed)
@@ -67,7 +71,8 @@ class StepLM:
         """Run the real prefill for this request (B=1); its last-position
         logits become the first decode sample."""
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
-        logits, caches = self._prefill(self.params, tokens)
+        logits, caches = _prefill(self.params, tokens, cfg=self.cfg,
+                                  rcfg=self.rcfg, max_len=self.max_len)
         self._caches[req.rid] = caches
         self._logits[req.rid] = logits
 
@@ -78,6 +83,11 @@ class StepLM:
     # -- decode --------------------------------------------------------------
 
     def _sample_row(self, req, logits_row: jax.Array) -> int:
+        if not bool(jnp.isfinite(logits_row).all()):
+            # argmax and categorical would still return a token
+            raise FloatingPointError(
+                f"request {req.rid}: non-finite logits at position "
+                f"{len(req.tokens) - 1}")
         if req.temperature <= 0:
             return int(jnp.argmax(logits_row))
         key = jax.random.fold_in(jax.random.fold_in(self._key, req.rid),
@@ -106,8 +116,9 @@ class StepLM:
                 *[self._caches[r.rid] for r in group])
             cur = jnp.asarray([[r.tokens[-1]] for r in group],
                               jnp.int32)
-            logits, caches = self._decode(self.params, caches, cur,
-                                          jnp.int32(pos))
+            logits, caches = _decode(self.params, caches, cur,
+                                     jnp.int32(pos), cfg=self.cfg,
+                                     rcfg=self.rcfg)
             for j, (i, req) in enumerate(zip(idxs, group)):
                 self._caches[req.rid] = jax.tree_util.tree_map(
                     lambda a, j=j: a[:, j:j + 1], caches)

@@ -54,6 +54,9 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600
     Raises on failure with combined output.
     """
     env = dict(os.environ)
+    # the child runs on host devices, never on an accelerator this
+    # machine may have (one process per chip: the child would hang)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", ""))
     env["TF_CPP_MIN_LOG_LEVEL"] = "2"

@@ -103,8 +103,11 @@ class TestInitEngine:
 
 
 class TestMatmul:
+    # (256, 640, 256): the last 512-wide k block runs 384 past K, and
+    # interpret mode fills what lies past the array with NaN
     @pytest.mark.parametrize("mkn", [(128, 128, 128), (200, 300, 150),
-                                     (512, 1024, 256), (64, 2048, 64)])
+                                     (512, 1024, 256), (64, 2048, 64),
+                                     (256, 640, 256)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matmul(self, mkn, dtype):
         from repro.kernels.matmul_dma import matmul, matmul_ref
@@ -130,6 +133,9 @@ class TestFlashAttention:
              cap=50.0),
         dict(B=1, Hq=2, Hkv=2, S=128, D=64, causal=False, window=0,
              cap=0.0),
+        # S=200: the last 128-wide kv tile runs past the keys
+        dict(B=1, Hq=4, Hkv=2, S=200, D=64, causal=True, window=0,
+             cap=50.0),
     ])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_vs_ref(self, case, dtype):
@@ -166,6 +172,8 @@ class TestDecodeAttention:
         dict(B=2, Hq=8, Hkv=2, S=512, D=64, kvlen=300, win=0),
         dict(B=1, Hq=4, Hkv=4, S=1024, D=128, kvlen=1024, win=0),
         dict(B=2, Hq=8, Hkv=4, S=2048, D=64, kvlen=1500, win=256),
+        # S=300: the last 256-wide tile runs past the cache
+        dict(B=2, Hq=8, Hkv=4, S=300, D=64, kvlen=300, win=0),
     ])
     def test_vs_ref(self, case):
         from repro.kernels.decode_attention import (decode_attention,

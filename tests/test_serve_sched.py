@@ -302,3 +302,77 @@ class TestStepLM:
         batched = run(make_reqs(), max_running=4)
         solo = run(make_reqs(), max_running=1)
         assert batched == solo
+
+    def test_nonfinite_logits_raise(self):
+        jax = pytest.importorskip("jax")
+        from repro.configs import get
+        from repro.configs.base import RunConfig, reduced
+        from repro.models import init_lm
+        from repro.serve.sched import StepLM
+        cfg = reduced(get("gemma2-2b"), n_layers=2, d_model=64,
+                      n_heads=2, n_kv_heads=1, d_ff=128, vocab=64)
+        params = init_lm(jax.random.PRNGKey(1), cfg)
+        params["final_norm"]["scale"] = params["final_norm"]["scale"] * \
+            float("nan")
+        model = StepLM(cfg, RunConfig(kernels="xla", dtype="float32"),
+                       params, max_len=32, row_bytes=LAYOUT.row_bytes)
+        fd = ServeFrontDoor(model, LAYOUT, max_seq_len=16)
+        fd.submit(ServeRequest(rid=0, prompt=[3, 4, 5], max_new_tokens=2))
+        with pytest.raises(FloatingPointError, match="non-finite"):
+            fd.run()
+
+
+class TestServeLauncher:
+    """`repro.launch.serve`: the front door + `StepLM` entry point."""
+
+    def test_reduced_serves_every_token_without_leaks(self):
+        from repro.launch.serve import parse_args, run
+        report = run(parse_args([
+            "--reduced", "--requests", "4", "--prompt-lens", "9,70",
+            "--new-tokens", "5"]))
+        assert report["kernels"] == "xla"
+        assert report["device"]["platform"] == "cpu"
+        assert report["requests"] == 4
+        assert report["new_tokens"] == 4 * 5
+        assert report["leaked_blocks"] == 0
+
+    def test_kv_layout_is_the_model_geometry(self):
+        from repro.configs import get
+        from repro.launch.serve import kv_layout, run_configs
+        cfg, rcfg = run_configs("gemma2-2b", reduced=False)
+        assert rcfg.kernels == "pallas" and rcfg.param_dtype == "bfloat16"
+        lay = kv_layout(cfg, rcfg, n_pages=8, page_size=16)
+        # 4 KV heads x head_dim 256 x 2-byte bfloat16
+        assert lay.row_bytes == 4 * 256 * 2 == 2048
+        assert cfg == get("gemma2-2b")
+
+    def test_full_width_refuses_a_cpu(self):
+        from repro.launch.serve import load, parse_args
+        with pytest.raises(SystemExit, match="needs a TPU"):
+            load(parse_args(["--arch", "gemma2-2b"]))
+
+    def test_params_are_stored_in_param_dtype(self):
+        import jax
+        import jax.numpy as jnp
+        from repro.configs import get
+        from repro.configs.base import RunConfig, reduced
+        from repro.models import init_lm_params
+        cfg = reduced(get("gemma2-2b"))
+        params = init_lm_params(jax.random.PRNGKey(0), cfg,
+                                RunConfig(param_dtype="bfloat16"))
+        dtypes = {a.dtype for a in jax.tree_util.tree_leaves(params)}
+        assert dtypes == {jnp.dtype(jnp.bfloat16)}
+
+    def test_same_seed_same_weights_in_every_process(self, subproc):
+        import jax
+        from repro.models.common import fold_keys
+        code = """
+            import jax
+            from repro.models.common import fold_keys
+            print([k.tolist() for k in fold_keys(jax.random.PRNGKey(3),
+                                                 "embed", "layers")])
+        """
+        here = [k.tolist() for k in fold_keys(jax.random.PRNGKey(3),
+                                              "embed", "layers")]
+        # the child hashes strings with another random seed
+        assert subproc(code, n_devices=1).strip() == str(here)
