@@ -42,6 +42,7 @@ from .frontend import CompletionEvent, IrqController
 from .legalizer import legalize_batch, legalize_tile
 from .midend import mp_dist_batch, mp_split_batch, tensor_nd_batch
 from .plan import PlanCache
+from repro import obs
 
 Descriptor = Union[Transfer1D, NdTransfer]
 
@@ -203,6 +204,7 @@ class IDMAEngine:
         pipeline: Sequence[object] = (),
         irq: Optional[object] = None,
         sanitize: Union[bool, str] = False,
+        recorder: Optional[obs.Recorder] = None,
     ) -> None:
         if num_backends > 1 and backend_boundary <= 0:
             raise ValueError("multi-back-end engines need backend_boundary")
@@ -256,6 +258,8 @@ class IDMAEngine:
         #: or a lazily derived snapshot for kwarg-built engines
         self._spec = None
         self.stats = EngineStats()
+        #: spans of each drain's phases (`repro.obs`)
+        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self._next_id = 1
         self._last_completed = 0
         self._fail_at: Optional[int] = None  # fault injection for tests
@@ -517,35 +521,39 @@ class IDMAEngine:
         #: item's drain position, and the pages continue-mode dropped
         fault_at: Dict[int, TransferError] = {}
         lower_faults: Dict[int, Tuple] = {}
-        for c, q in enumerate(self._queues):
-            parts: List[LoweredPort] = []
-            off = 0
-            for tid0, _, payload in q:
-                try:
-                    lps = self._lower_ports(payload)
-                except TransferError as err:
-                    fault_at[tid0] = err
-                    lps = []
-                if self._last_lower_faults:
-                    lower_faults[tid0] = tuple(self._last_lower_faults)
-                lowered[tid0] = lps
-                count = sum(len(lp.batch) for lp in lps)
-                if count:       # burst span in channel c's stream
-                    spans.setdefault(tid0, []).append((c, off, count))
-                    off += count
-                parts.extend(lps)
-            nonempty = [lp for lp in parts if len(lp.batch)]
-            streams.append(concat_batches([lp.batch for lp in nonempty]))
-            if beats_ok and nonempty and \
-                    all(lp.beats is not None for lp in nonempty):
-                stream_beats.append(
-                    nonempty[0].beats if len(nonempty) == 1 else
-                    np.concatenate([lp.beats for lp in nonempty]))
-            else:
-                stream_beats.append(None)
-        result = sim.simulate_channels(
-            streams, self.sim_config, (self.src_system, self.dst_system),
-            already_legal=True, beats=stream_beats, tie_seed=tie_seed)
+        recorder = self.recorder
+        with recorder.span("engine.lower"):
+            for c, q in enumerate(self._queues):
+                parts: List[LoweredPort] = []
+                off = 0
+                for tid0, _, payload in q:
+                    try:
+                        lps = self._lower_ports(payload)
+                    except TransferError as err:
+                        fault_at[tid0] = err
+                        lps = []
+                    if self._last_lower_faults:
+                        lower_faults[tid0] = tuple(self._last_lower_faults)
+                    lowered[tid0] = lps
+                    count = sum(len(lp.batch) for lp in lps)
+                    if count:       # burst span in channel c's stream
+                        spans.setdefault(tid0, []).append((c, off, count))
+                        off += count
+                    parts.extend(lps)
+                nonempty = [lp for lp in parts if len(lp.batch)]
+                streams.append(
+                    concat_batches([lp.batch for lp in nonempty]))
+                if beats_ok and nonempty and \
+                        all(lp.beats is not None for lp in nonempty):
+                    stream_beats.append(
+                        nonempty[0].beats if len(nonempty) == 1 else
+                        np.concatenate([lp.beats for lp in nonempty]))
+                else:
+                    stream_beats.append(None)
+        with recorder.span("engine.simulate"):
+            result = sim.simulate_channels(
+                streams, self.sim_config, (self.src_system, self.dst_system),
+                already_legal=True, beats=stream_beats, tie_seed=tie_seed)
         self.last_channel_result = result
 
         def span_cycle(tid0: int) -> int:
@@ -569,24 +577,46 @@ class IDMAEngine:
         events: List[CompletionEvent] = []
         rec_cycle: Dict[int, int] = {}
         try:
-            for k, (tid0, channel, payload) in enumerate(items):
-                rec = self._record_for(tid0)
-                before = self.stats.bytes_moved
-                try:
-                    lowering_fault = fault_at.get(tid0)
-                    if lowering_fault is not None:
-                        raise lowering_fault
-                    self._run_ports(lowered[tid0])
-                    if isinstance(payload, DescriptorBatch):
-                        count = len(payload)
-                        last = int(payload.transfer_id[-1])
-                    else:
-                        count = 1
-                        last = tid0
-                except TransferError:
+            with recorder.span("engine.execute"):
+                for k, (tid0, channel, payload) in enumerate(items):
+                    rec = self._record_for(tid0)
+                    before = self.stats.bytes_moved
+                    try:
+                        lowering_fault = fault_at.get(tid0)
+                        if lowering_fault is not None:
+                            raise lowering_fault
+                        self._run_ports(lowered[tid0])
+                        if isinstance(payload, DescriptorBatch):
+                            count = len(payload)
+                            last = int(payload.transfer_id[-1])
+                        else:
+                            count = 1
+                            last = tid0
+                    except TransferError:
+                        if rec is not None:
+                            first = rec.status != "error"
+                            rec.status = "error"     # terminal
+                            rec.pending -= 1
+                            rec.bytes_moved += \
+                                self.stats.bytes_moved - before
+                            pages = lower_faults.get(tid0)
+                            if pages:
+                                rec.faulted_pages = \
+                                    rec.faulted_pages + pages
+                            cyc = max(rec_cycle.get(rec.tid, 0),
+                                      span_cycle(tid0))
+                            if first:   # one interrupt per record: a later
+                                # shard of an already-errored dispatch must
+                                # not re-raise the vector
+                                events.append(CompletionEvent(
+                                    tid=rec.tid, count=rec.count,
+                                    channel=rec.channel, cycle=cyc,
+                                    status="error",
+                                    bytes_moved=rec.bytes_moved))
+                        for it in items[k + 1:]:    # failed item is consumed
+                            self._queues[it[1]].append(it)
+                        raise
                     if rec is not None:
-                        first = rec.status != "error"
-                        rec.status = "error"     # terminal
                         rec.pending -= 1
                         rec.bytes_moved += self.stats.bytes_moved - before
                         pages = lower_faults.get(tid0)
@@ -594,39 +624,23 @@ class IDMAEngine:
                             rec.faulted_pages = rec.faulted_pages + pages
                         cyc = max(rec_cycle.get(rec.tid, 0),
                                   span_cycle(tid0))
-                        if first:   # one interrupt per record: a later
-                            # shard of an already-errored dispatch must
-                            # not re-raise the vector
+                        rec_cycle[rec.tid] = cyc
+                        if rec.pending <= 0 and rec.status != "error":
                             events.append(CompletionEvent(
                                 tid=rec.tid, count=rec.count,
-                                channel=rec.channel, cycle=cyc,
-                                status="error", bytes_moved=rec.bytes_moved))
-                    for it in items[k + 1:]:    # failed item is consumed
-                        self._queues[it[1]].append(it)
-                    raise
-                if rec is not None:
-                    rec.pending -= 1
-                    rec.bytes_moved += self.stats.bytes_moved - before
-                    pages = lower_faults.get(tid0)
-                    if pages:
-                        rec.faulted_pages = rec.faulted_pages + pages
-                    cyc = max(rec_cycle.get(rec.tid, 0), span_cycle(tid0))
-                    rec_cycle[rec.tid] = cyc
-                    if rec.pending <= 0 and rec.status != "error":
-                        events.append(CompletionEvent(
-                            tid=rec.tid, count=rec.count,
-                            channel=rec.channel, cycle=cyc, status="done",
-                            bytes_moved=rec.bytes_moved))
-                self.stats.completed += count
-                self._last_completed = last
+                                channel=rec.channel, cycle=cyc, status="done",
+                                bytes_moved=rec.bytes_moved))
+                    self.stats.completed += count
+                    self._last_completed = last
         finally:
             # interrupt delivery — also on the abort path, so the error
             # event and every completion before it reach the callbacks
             result.backoff_cycles = self._drain_backoff
             self.stats.backoff_cycles += self._drain_backoff
-            for ev in sorted(events, key=lambda e: (e.cycle, e.tid)):
-                self.irq.post(ev)
-            self.irq.flush()
+            with recorder.span("engine.deliver"):
+                for ev in sorted(events, key=lambda e: (e.cycle, e.tid)):
+                    self.irq.post(ev)
+                self.irq.flush()
         return result
 
     def on_complete(self, callback) -> None:

@@ -23,6 +23,10 @@ advances the state machine — "KV move done → request runnable".
 `engine.poll` register-read adapter after each drain; both modes drive
 identical schedules (tested).
 
+Host spans and counters go to a `repro.obs.Recorder` shared with the
+engine and, where it takes one, the model (`frontdoor.*`, `engine.*`,
+`request.queued` / `request.staged`, counter ``plane_bytes``).
+
 Time is **simulated engine cycles**: each drain advances the clock by
 its `ChannelSimResult.total_cycles`, plus a fixed per-step
 ``step_overhead_cycles`` modeling the model-compute phase.  Poisson
@@ -33,11 +37,12 @@ are all measured on this clock, so the benchmark is deterministic.
 from __future__ import annotations
 
 import heapq
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import (BackendSpec, ChannelSpec, EngineConfig,
                         EngineSpec, FrontendSpec, IrqSpec, MemoryMap,
                         PlanCache, Protocol, VMEM_ENDPOINT, build_engine,
@@ -88,7 +93,6 @@ class ServeMetrics:
     cycles: int = 0
     decode_tokens: int = 0
     prefill_rows: int = 0
-    per_step: List[StepMetrics] = field(default_factory=list)
 
     def tokens_per_mcycle(self) -> float:
         return self.decode_tokens / (self.cycles / 1e6) if self.cycles \
@@ -102,7 +106,9 @@ class ServeFrontDoor:
     or the jax `StepLM` binding); ``layout`` sizes the HBM pool
     (``layout.n_pages`` blocks).  Per-request VMEM staging/gather
     regions are sized for ``max_running`` concurrent requests of up to
-    ``max_seq_len`` tokens.
+    ``max_seq_len`` tokens.  ``recorder`` takes the spans and counters
+    of every step, its engine's drains included (default: the shared
+    one, off).
     """
 
     def __init__(self, model, layout: KVLayout, *,
@@ -113,7 +119,8 @@ class ServeFrontDoor:
                  irq: Optional[IrqSpec] = None,
                  plan_cache: int = 256, spec: Optional[EngineSpec] = None,
                  step_overhead_cycles: int = 1000,
-                 sanitize: bool = False) -> None:
+                 sanitize: bool = False,
+                 recorder: Optional[obs.Recorder] = None) -> None:
         if completion not in ("irq", "poll"):
             raise ValueError(f"completion must be 'irq' or 'poll', "
                              f"got {completion!r}")
@@ -124,6 +131,7 @@ class ServeFrontDoor:
         if n_swap_slots is None:
             n_swap_slots = 2 * layout.n_pages
         self.completion = completion
+        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self.step_overhead_cycles = step_overhead_cycles
 
         self.alloc = BlockAllocator(layout.n_pages,
@@ -148,7 +156,8 @@ class ServeFrontDoor:
         self.plan_cache = PlanCache(capacity=plan_cache)
         self.engine = build_engine(spec, mem=mem,
                                    plan_cache=self.plan_cache,
-                                   sanitize=sanitize)
+                                   sanitize=sanitize,
+                                   recorder=self.recorder)
         if completion == "irq":
             self.engine.on_complete(self._on_irq)
 
@@ -188,6 +197,7 @@ class ServeFrontDoor:
         heapq.heappush(self._arrivals,
                        (req.arrival_cycle, self._arrival_seq, req))
         self._arrival_seq += 1
+        self.recorder.begin("request.queued", req.rid)
 
     # -- completion delivery -------------------------------------------------
 
@@ -219,31 +229,35 @@ class ServeFrontDoor:
         the request's VMEM staging region."""
         vmem = self.engine.mem.spaces[Protocol.VMEM]
         n = (end - start) * self.layout.row_bytes
-        for which, base in (("k", self._sk(req.slot)),
-                            ("v", self._sv(req.slot))):
-            rows = self.model.kv_rows(req.seed, req.tokens, start, end,
-                                      which)
-            vmem[base:base + n] = rows.reshape(-1)
+        with self.recorder.span("frontdoor.stage", req.rid):
+            for which, base in (("k", self._sk(req.slot)),
+                                ("v", self._sv(req.slot))):
+                rows = self.model.kv_rows(req.seed, req.tokens, start, end,
+                                          which)
+                vmem[base:base + n] = rows.reshape(-1)
 
     def _dispatch_append(self, req: ServeRequest, start: int, end: int,
                          kind: str, arg: Optional[int] = None) -> None:
         self._stage_rows(req, start, end)
-        self._dispatch(span_append_descriptors(
-            self.layout, req.blocks, start, end,
-            stage_k=self._sk(req.slot), stage_v=self._sv(req.slot)),
-            kind, req, arg)
+        with self.recorder.span("frontdoor.build", req.rid):
+            self._dispatch(span_append_descriptors(
+                self.layout, req.blocks, start, end,
+                stage_k=self._sk(req.slot), stage_v=self._sv(req.slot)),
+                kind, req, arg)
 
     def _dispatch_gather(self, req: ServeRequest) -> None:
         lay = self.layout
         n = self.sched.pages_for(len(req.tokens))
         table = np.asarray(req.blocks[:n], dtype=np.int64)[None, :]
-        self._dispatch(concat_batches([
-            gather_descriptors(lay, table, n * lay.page_size,
-                               pool_base=0, dst_base=self._gk(req.slot)),
-            gather_descriptors(lay, table, n * lay.page_size,
-                               pool_base=lay.pool_bytes,
-                               dst_base=self._gv(req.slot)),
-        ]), "gather", req)
+        with self.recorder.span("frontdoor.build", req.rid):
+            self._dispatch(concat_batches([
+                gather_descriptors(lay, table, n * lay.page_size,
+                                   pool_base=0,
+                                   dst_base=self._gk(req.slot)),
+                gather_descriptors(lay, table, n * lay.page_size,
+                                   pool_base=lay.pool_bytes,
+                                   dst_base=self._gv(req.slot)),
+            ]), "gather", req)
 
     def _gathered_bytes(self, req: ServeRequest
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -259,36 +273,50 @@ class ServeFrontDoor:
     # -- the serving step ----------------------------------------------------
 
     def _drain(self) -> int:
-        res = self.engine.wait_all()
-        if self.completion == "poll":
-            self._poll_pending()
+        with self.recorder.span("frontdoor.drain"):
+            res = self.engine.wait_all()
+            if self.completion == "poll":
+                self._poll_pending()
         return res.total_cycles
 
     def step(self) -> Optional[StepMetrics]:
         """One scheduler step; returns its metrics, or None when there
         was nothing to do (drained and no arrival due)."""
-        # idle fast-forward: jump the clock to the next arrival
-        if self.sched.drained():
-            if not self._arrivals:
-                return None
-            self.clock = max(self.clock, self._arrivals[0][0])
-        while self._arrivals and self._arrivals[0][0] <= self.clock:
-            _, _, req = heapq.heappop(self._arrivals)
-            self.sched.submit(req)
+        if self.sched.drained() and not self._arrivals:
+            return None
+        with self.recorder.span("frontdoor.step"):
+            moved = self.engine.stats.bytes_moved
+            m = self._step()
+            self.recorder.count("plane_bytes",
+                                self.engine.stats.bytes_moved - moved)
+        return m
 
-        plan = self.sched.plan_step()
+    def _step(self) -> StepMetrics:
+        rec = self.recorder
+        with rec.span("frontdoor.plan"):
+            # idle fast-forward: jump the clock to the next arrival
+            if self.sched.drained():
+                self.clock = max(self.clock, self._arrivals[0][0])
+            while self._arrivals and self._arrivals[0][0] <= self.clock:
+                _, _, req = heapq.heappop(self._arrivals)
+                self.sched.submit(req)
+            plan = self.sched.plan_step()
         for req in plan.admitted:
+            rec.end("request.queued", req.rid)
+            rec.begin("request.staged", req.rid)
             self.model.on_admit(req)
 
         # -- move drain: swaps + prefill chunks + decode gathers
-        for req in plan.swap_out:
-            self._dispatch(swap_descriptors(self.layout, req.blocks,
-                                            req.swap_slots, "out"),
-                           "swap_out", req)
-        for req in plan.swap_in:
-            self._dispatch(swap_descriptors(self.layout, req.blocks,
-                                            req.swap_slots, "in"),
-                           "swap_in", req)
+        if plan.swap_out or plan.swap_in:
+            with rec.span("frontdoor.build"):
+                for req in plan.swap_out:
+                    self._dispatch(swap_descriptors(
+                        self.layout, req.blocks, req.swap_slots, "out"),
+                        "swap_out", req)
+                for req in plan.swap_in:
+                    self._dispatch(swap_descriptors(
+                        self.layout, req.blocks, req.swap_slots, "in"),
+                        "swap_in", req)
         for req, start, end in plan.prefill:
             self._dispatch_append(req, start, end, "prefill", end)
         for req in plan.decode:
@@ -296,6 +324,9 @@ class ServeFrontDoor:
         cycles = self._drain()
 
         # -- sample + append drain
+        for req in plan.decode:
+            if not req.output:
+                rec.end("request.staged", req.rid)
         gathered = [self._gathered_bytes(r) for r in plan.decode]
         toks = self.model.next_tokens(plan.decode, gathered)
         appends = 0
@@ -337,7 +368,6 @@ class ServeFrontDoor:
         self.metrics.cycles += cycles
         self.metrics.decode_tokens += m.decode_tokens
         self.metrics.prefill_rows += m.prefill_rows
-        self.metrics.per_step.append(m)
         return m
 
     def run(self, max_steps: int = 1_000_000) -> ServeMetrics:

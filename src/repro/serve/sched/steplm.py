@@ -21,16 +21,22 @@ history (`HashLM.kv_rows`).  The mirror keeps the descriptor plane
 honest — a corrupted swap or a mis-gathered page would change gathered
 bytes that tests digest-check — without forcing the float cache layout
 through the byte pool.
+
+Spans and counters (`repro.obs`): ``model.prefill``, ``model.stack`` /
+``model.decode`` / ``model.unstack`` around a group's decode call,
+``model.sample`` per row with a ``model.sync`` inside it for each
+blocking device→host read; counters ``host_syncs`` and ``rows_sampled``.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ArchConfig, RunConfig
 from repro.models import lm_decode_step, lm_prefill
 from .model import HashLM
@@ -46,13 +52,15 @@ class StepLM:
 
     def __init__(self, cfg: ArchConfig, rcfg: RunConfig, params,
                  max_len: int, row_bytes: int, eos_token: int = -1,
-                 seed: int = 0) -> None:
+                 seed: int = 0,
+                 recorder: Optional[obs.Recorder] = None) -> None:
         self.cfg = cfg
         self.rcfg = rcfg
         self.vocab = cfg.vocab_size
         self.eos_token = eos_token
         self.params = params
         self.max_len = max_len
+        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self._mirror = HashLM(row_bytes, vocab=self.vocab,
                               eos_token=eos_token, seed=seed)
         self._key = jax.random.PRNGKey(seed)
@@ -71,8 +79,9 @@ class StepLM:
         """Run the real prefill for this request (B=1); its last-position
         logits become the first decode sample."""
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
-        logits, caches = _prefill(self.params, tokens, cfg=self.cfg,
-                                  rcfg=self.rcfg, max_len=self.max_len)
+        with self.recorder.span("model.prefill", req.rid):
+            logits, caches = _prefill(self.params, tokens, cfg=self.cfg,
+                                      rcfg=self.rcfg, max_len=self.max_len)
         self._caches[req.rid] = caches
         self._logits[req.rid] = logits
 
@@ -82,18 +91,27 @@ class StepLM:
 
     # -- decode --------------------------------------------------------------
 
+    def _read(self, value: jax.Array, to: Callable):
+        """``to(value)``: one blocking device→host read."""
+        self.recorder.count("host_syncs")
+        with self.recorder.span("model.sync"):
+            return to(value)
+
     def _sample_row(self, req, logits_row: jax.Array) -> int:
-        if not bool(jnp.isfinite(logits_row).all()):
-            # argmax and categorical would still return a token
-            raise FloatingPointError(
-                f"request {req.rid}: non-finite logits at position "
-                f"{len(req.tokens) - 1}")
-        if req.temperature <= 0:
-            return int(jnp.argmax(logits_row))
-        key = jax.random.fold_in(jax.random.fold_in(self._key, req.rid),
-                                 len(req.tokens))
-        return int(jax.random.categorical(
-            key, logits_row / max(req.temperature, 1e-4)))
+        rec = self.recorder
+        with rec.span("model.sample", req.rid):
+            rec.count("rows_sampled")
+            if not self._read(jnp.isfinite(logits_row).all(), bool):
+                # argmax and categorical would still return a token
+                raise FloatingPointError(
+                    f"request {req.rid}: non-finite logits at position "
+                    f"{len(req.tokens) - 1}")
+            if req.temperature <= 0:
+                return self._read(jnp.argmax(logits_row), int)
+            key = jax.random.fold_in(
+                jax.random.fold_in(self._key, req.rid), len(req.tokens))
+            return self._read(jax.random.categorical(
+                key, logits_row / max(req.temperature, 1e-4)), int)
 
     def next_tokens(self, reqs, gathered: List[Tuple[np.ndarray,
                                                      np.ndarray]]
@@ -109,18 +127,22 @@ class StepLM:
                 out[i] = self._sample_row(req, self._logits.pop(req.rid)[0])
             else:
                 by_pos.setdefault(len(req.tokens) - 1, []).append(i)
+        rec = self.recorder
         for pos, idxs in sorted(by_pos.items()):
             group = [reqs[i] for i in idxs]
-            caches = jax.tree_util.tree_map(
-                lambda *leaves: jnp.concatenate(leaves, axis=1),
-                *[self._caches[r.rid] for r in group])
-            cur = jnp.asarray([[r.tokens[-1]] for r in group],
-                              jnp.int32)
-            logits, caches = _decode(self.params, caches, cur,
-                                     jnp.int32(pos), cfg=self.cfg,
-                                     rcfg=self.rcfg)
+            with rec.span("model.stack"):
+                caches = jax.tree_util.tree_map(
+                    lambda *leaves: jnp.concatenate(leaves, axis=1),
+                    *[self._caches[r.rid] for r in group])
+                cur = jnp.asarray([[r.tokens[-1]] for r in group],
+                                  jnp.int32)
+            with rec.span("model.decode"):
+                logits, caches = _decode(self.params, caches, cur,
+                                         jnp.int32(pos), cfg=self.cfg,
+                                         rcfg=self.rcfg)
             for j, (i, req) in enumerate(zip(idxs, group)):
-                self._caches[req.rid] = jax.tree_util.tree_map(
-                    lambda a, j=j: a[:, j:j + 1], caches)
+                with rec.span("model.unstack", req.rid):
+                    self._caches[req.rid] = jax.tree_util.tree_map(
+                        lambda a, j=j: a[:, j:j + 1], caches)
                 out[i] = self._sample_row(req, logits[j])
         return out
