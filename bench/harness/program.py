@@ -11,8 +11,11 @@ take plain tuples, so that a test can feed them a small synthetic trace:
   recorder events (name, start_ns, end_ns, parent, rid)
   counters        {name: int}
 
-`bench/run.py` does not turn a recorder on yet; PERF.md §7 lists the
-edits of the harness that would.
+`bench/run.py` does not turn the recorder on yet; PERF.md §7 lists the
+edits of the harness that would.  `load_spans` repeats the host loop of
+`trace.load`, and `innermost` / `idle_by_span` the pass of
+`trace.activity` / `idle_by_activity`, for another prefix and any span
+name; the same edits would merge each pair.
 """
 
 from __future__ import annotations
@@ -22,11 +25,8 @@ import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from bench.harness.trace import Interval, clip, gaps
+from repro.obs import ANNOTATION_PREFIX, Event
 
-# `repro.obs`'s names, kept here so that the harness imports this module
-# on a program that has no recorder
-ANNOTATION_PREFIX = "repro."
-Event = Tuple[str, int, int, Optional[int], Optional[int]]
 NONE = "none"
 # the front door's own work on the descriptor plane, per step
 PLANE = ("frontdoor.stage", "frontdoor.build", "frontdoor.drain")
@@ -156,10 +156,12 @@ def plane_bytes_per_step(events: Sequence[Event],
     return counters["plane_bytes"] / steps / 1e6
 
 
-def host_syncs_per_token(counters: Dict[str, int]) -> Optional[float]:
-    """Blocking device→host reads per sampled token."""
-    rows = counters.get("rows_sampled", 0)
-    return counters.get("host_syncs", 0) / rows if rows else None
+def host_syncs_per_token(events: Sequence[Event]) -> Optional[float]:
+    """Blocking device→host reads (``model.sync``) per sampled token
+    (``model.sample``)."""
+    rows = len(durations_ns(events, "model.sample"))
+    return len(durations_ns(events, "model.sync")) / rows if rows \
+        else None
 
 
 def queue_wait_ms_mean(events: Sequence[Event]) -> Optional[float]:

@@ -50,14 +50,17 @@ def test_recorder_reductions():
               ("frontdoor.drain", 6 * ms, 8 * ms, 1, None),
               ("engine.execute", 6 * ms, 7 * ms, 5, None),
               ("model.sample", 9 * ms, 10 * ms, 1, 1),
+              ("model.sync", 9 * ms, 9 * ms + 1, 7, None),
+              ("model.sync", 10 * ms - 1, 10 * ms, 7, None),
               ("frontdoor.step", 10 * ms, 12 * ms, None, None),
-              ("frontdoor.build", 10 * ms, 11 * ms, 8, 1),
-              ("model.sample", 11 * ms, 12 * ms, 8, 1)]
-    counters = {"plane_bytes": 3_000_000, "host_syncs": 4,
-                "rows_sampled": 2}
+              ("frontdoor.build", 10 * ms, 11 * ms, 10, 1),
+              ("model.sample", 11 * ms, 12 * ms, 10, 1),
+              ("model.sync", 11 * ms, 11 * ms + 1, 12, None),
+              ("model.sync", 11 * ms + 1, 11 * ms + 2, 12, None)]
+    counters = {"plane_bytes": 3_000_000}
     assert P.plane_ms_per_step(events) == pytest.approx(2.0)
     assert P.plane_bytes_per_step(events, counters) == pytest.approx(1.5)
-    assert P.host_syncs_per_token(counters) == 2.0
+    assert P.host_syncs_per_token(events) == 2.0
     assert P.queue_wait_ms_mean(events) == pytest.approx(4.0)
     assert P.ttft_split_ms(events) == pytest.approx(
         {"queued": 4.0, "staged": 5.0, "first_sample": 1.0})
@@ -65,7 +68,7 @@ def test_recorder_reductions():
     assert table["frontdoor.step"] == pytest.approx((2, 8e-3, 1e-3))
     assert table["frontdoor.drain"] == pytest.approx((1, 2e-3, 1e-3))
     assert P.plane_ms_per_step([]) is None
-    assert P.host_syncs_per_token({}) is None
+    assert P.host_syncs_per_token(events[:7]) is None
     assert P.queue_wait_ms_mean(events[1:]) is None
 
 
@@ -118,8 +121,8 @@ def test_reduced_traced_run_with_the_recorder_on(monkeypatch):
     values = [P.plane_ms_per_step(events),
               P.plane_bytes_per_step(events, counters),
               P.plane_idle_share(idle, t0, t1),
-              P.host_syncs_per_token(counters),
+              P.host_syncs_per_token(events),
               P.queue_wait_ms_mean(events)]
     values += list(P.ttft_split_ms(events).values())
     assert all(v is not None and math.isfinite(v) for v in values), values
-    assert P.host_syncs_per_token(counters) == 2.0
+    assert P.host_syncs_per_token(events) == 2.0

@@ -204,7 +204,6 @@ class IDMAEngine:
         pipeline: Sequence[object] = (),
         irq: Optional[object] = None,
         sanitize: Union[bool, str] = False,
-        recorder: Optional[obs.Recorder] = None,
     ) -> None:
         if num_backends > 1 and backend_boundary <= 0:
             raise ValueError("multi-back-end engines need backend_boundary")
@@ -258,8 +257,6 @@ class IDMAEngine:
         #: or a lazily derived snapshot for kwarg-built engines
         self._spec = None
         self.stats = EngineStats()
-        #: spans of each drain's phases (`repro.obs`)
-        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self._next_id = 1
         self._last_completed = 0
         self._fail_at: Optional[int] = None  # fault injection for tests
@@ -521,7 +518,7 @@ class IDMAEngine:
         #: item's drain position, and the pages continue-mode dropped
         fault_at: Dict[int, TransferError] = {}
         lower_faults: Dict[int, Tuple] = {}
-        recorder = self.recorder
+        recorder = obs.DEFAULT      # spans of the drain's phases
         with recorder.span("engine.lower"):
             for c, q in enumerate(self._queues):
                 parts: List[LoweredPort] = []

@@ -515,7 +515,6 @@ def build_engine(spec: EngineSpec,
                  mem: Optional["MemoryMap"] = None,
                  plan_cache: Union[None, bool, int, PlanCache] = None,
                  sanitize: Union[bool, str] = False,
-                 recorder=None,
                  ) -> IDMAEngine:
     """Instantiate an `IDMAEngine` from a validated `EngineSpec`.
 
@@ -525,9 +524,7 @@ def build_engine(spec: EngineSpec,
     `PlanCache`, an existing `PlanCache` is shared as-is;
     ``sanitize``   — opt into the `repro.sanitize` static analyzer on
     every drain (``True``/``"raise"`` raises `SanitizeError` on a hazard,
-    ``"warn"`` warns and drains anyway);
-    ``recorder``   — the `repro.obs.Recorder` of the drains' spans
-    (default: the shared one, off).
+    ``"warn"`` warns and drains anyway).
     """
     from .backend import MemoryMap
     if mem is None and spec.mem_spaces:
@@ -558,7 +555,6 @@ def build_engine(spec: EngineSpec,
         plan_cache=cache,
         irq=spec.irq,
         sanitize=sanitize,
-        recorder=recorder,
     )
     eng._spec = spec
     return eng

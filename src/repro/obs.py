@@ -1,9 +1,10 @@
 """Spans and counters of the serving path.
 
-One `Recorder` is shared by the layers of a serving stack: the front
-door (`serve.sched.front`), the descriptor engine (`core.engine`) and
-the model binding (`serve.sched.steplm`).  Each takes an optional
-``recorder`` and otherwise uses `DEFAULT`, which is off.
+One `Recorder`, `DEFAULT`, is shared by the layers of a serving stack:
+the front door (`serve.sched.front`), the descriptor engine
+(`core.engine`) and the model binding (`serve.sched.steplm`) each read
+it at every span.  It is off; setting ``DEFAULT.enabled`` between steps
+turns on the spans of every layer at once.
 
 * ``span(name, rid=None)`` — a context manager that records
   ``(name, start_ns, end_ns, parent, rid)`` on `time.perf_counter_ns`;
@@ -114,5 +115,5 @@ class Recorder:
         self._begun.clear()
 
 
-#: the recorder of every layer not given one; off
+#: the recorder of every layer; off
 DEFAULT = Recorder()

@@ -23,8 +23,8 @@ advances the state machine — "KV move done → request runnable".
 `engine.poll` register-read adapter after each drain; both modes drive
 identical schedules (tested).
 
-Host spans and counters go to a `repro.obs.Recorder` shared with the
-engine and, where it takes one, the model (`frontdoor.*`, `engine.*`,
+Host spans and counters go to the one `repro.obs` recorder, which the
+engine and the model share (`frontdoor.*`, `engine.*`,
 `request.queued` / `request.staged`, counter ``plane_bytes``).
 
 Time is **simulated engine cycles**: each drain advances the clock by
@@ -106,9 +106,7 @@ class ServeFrontDoor:
     or the jax `StepLM` binding); ``layout`` sizes the HBM pool
     (``layout.n_pages`` blocks).  Per-request VMEM staging/gather
     regions are sized for ``max_running`` concurrent requests of up to
-    ``max_seq_len`` tokens.  ``recorder`` takes the spans and counters
-    of every step, its engine's drains included (default: the shared
-    one, off).
+    ``max_seq_len`` tokens.
     """
 
     def __init__(self, model, layout: KVLayout, *,
@@ -119,8 +117,7 @@ class ServeFrontDoor:
                  irq: Optional[IrqSpec] = None,
                  plan_cache: int = 256, spec: Optional[EngineSpec] = None,
                  step_overhead_cycles: int = 1000,
-                 sanitize: bool = False,
-                 recorder: Optional[obs.Recorder] = None) -> None:
+                 sanitize: bool = False) -> None:
         if completion not in ("irq", "poll"):
             raise ValueError(f"completion must be 'irq' or 'poll', "
                              f"got {completion!r}")
@@ -131,7 +128,6 @@ class ServeFrontDoor:
         if n_swap_slots is None:
             n_swap_slots = 2 * layout.n_pages
         self.completion = completion
-        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self.step_overhead_cycles = step_overhead_cycles
 
         self.alloc = BlockAllocator(layout.n_pages,
@@ -156,8 +152,7 @@ class ServeFrontDoor:
         self.plan_cache = PlanCache(capacity=plan_cache)
         self.engine = build_engine(spec, mem=mem,
                                    plan_cache=self.plan_cache,
-                                   sanitize=sanitize,
-                                   recorder=self.recorder)
+                                   sanitize=sanitize)
         if completion == "irq":
             self.engine.on_complete(self._on_irq)
 
@@ -197,7 +192,7 @@ class ServeFrontDoor:
         heapq.heappush(self._arrivals,
                        (req.arrival_cycle, self._arrival_seq, req))
         self._arrival_seq += 1
-        self.recorder.begin("request.queued", req.rid)
+        obs.DEFAULT.begin("request.queued", req.rid)
 
     # -- completion delivery -------------------------------------------------
 
@@ -229,7 +224,7 @@ class ServeFrontDoor:
         the request's VMEM staging region."""
         vmem = self.engine.mem.spaces[Protocol.VMEM]
         n = (end - start) * self.layout.row_bytes
-        with self.recorder.span("frontdoor.stage", req.rid):
+        with obs.DEFAULT.span("frontdoor.stage", req.rid):
             for which, base in (("k", self._sk(req.slot)),
                                 ("v", self._sv(req.slot))):
                 rows = self.model.kv_rows(req.seed, req.tokens, start, end,
@@ -239,7 +234,7 @@ class ServeFrontDoor:
     def _dispatch_append(self, req: ServeRequest, start: int, end: int,
                          kind: str, arg: Optional[int] = None) -> None:
         self._stage_rows(req, start, end)
-        with self.recorder.span("frontdoor.build", req.rid):
+        with obs.DEFAULT.span("frontdoor.build", req.rid):
             self._dispatch(span_append_descriptors(
                 self.layout, req.blocks, start, end,
                 stage_k=self._sk(req.slot), stage_v=self._sv(req.slot)),
@@ -249,7 +244,7 @@ class ServeFrontDoor:
         lay = self.layout
         n = self.sched.pages_for(len(req.tokens))
         table = np.asarray(req.blocks[:n], dtype=np.int64)[None, :]
-        with self.recorder.span("frontdoor.build", req.rid):
+        with obs.DEFAULT.span("frontdoor.build", req.rid):
             self._dispatch(concat_batches([
                 gather_descriptors(lay, table, n * lay.page_size,
                                    pool_base=0,
@@ -273,7 +268,7 @@ class ServeFrontDoor:
     # -- the serving step ----------------------------------------------------
 
     def _drain(self) -> int:
-        with self.recorder.span("frontdoor.drain"):
+        with obs.DEFAULT.span("frontdoor.drain"):
             res = self.engine.wait_all()
             if self.completion == "poll":
                 self._poll_pending()
@@ -284,15 +279,15 @@ class ServeFrontDoor:
         was nothing to do (drained and no arrival due)."""
         if self.sched.drained() and not self._arrivals:
             return None
-        with self.recorder.span("frontdoor.step"):
+        rec = obs.DEFAULT
+        with rec.span("frontdoor.step"):
             moved = self.engine.stats.bytes_moved
             m = self._step()
-            self.recorder.count("plane_bytes",
-                                self.engine.stats.bytes_moved - moved)
+            rec.count("plane_bytes", self.engine.stats.bytes_moved - moved)
         return m
 
     def _step(self) -> StepMetrics:
-        rec = self.recorder
+        rec = obs.DEFAULT
         with rec.span("frontdoor.plan"):
             # idle fast-forward: jump the clock to the next arrival
             if self.sched.drained():

@@ -22,15 +22,15 @@ honest — a corrupted swap or a mis-gathered page would change gathered
 bytes that tests digest-check — without forcing the float cache layout
 through the byte pool.
 
-Spans and counters (`repro.obs`): ``model.prefill``, ``model.stack`` /
+Spans (`repro.obs`): ``model.prefill``, ``model.stack`` /
 ``model.decode`` / ``model.unstack`` around a group's decode call,
 ``model.sample`` per row with a ``model.sync`` inside it for each
-blocking device→host read; counters ``host_syncs`` and ``rows_sampled``.
+blocking device→host read.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,15 +52,13 @@ class StepLM:
 
     def __init__(self, cfg: ArchConfig, rcfg: RunConfig, params,
                  max_len: int, row_bytes: int, eos_token: int = -1,
-                 seed: int = 0,
-                 recorder: Optional[obs.Recorder] = None) -> None:
+                 seed: int = 0) -> None:
         self.cfg = cfg
         self.rcfg = rcfg
         self.vocab = cfg.vocab_size
         self.eos_token = eos_token
         self.params = params
         self.max_len = max_len
-        self.recorder = recorder if recorder is not None else obs.DEFAULT
         self._mirror = HashLM(row_bytes, vocab=self.vocab,
                               eos_token=eos_token, seed=seed)
         self._key = jax.random.PRNGKey(seed)
@@ -79,7 +77,7 @@ class StepLM:
         """Run the real prefill for this request (B=1); its last-position
         logits become the first decode sample."""
         tokens = jnp.asarray(np.asarray(req.prompt, np.int32))[None, :]
-        with self.recorder.span("model.prefill", req.rid):
+        with obs.DEFAULT.span("model.prefill", req.rid):
             logits, caches = _prefill(self.params, tokens, cfg=self.cfg,
                                       rcfg=self.rcfg, max_len=self.max_len)
         self._caches[req.rid] = caches
@@ -91,16 +89,14 @@ class StepLM:
 
     # -- decode --------------------------------------------------------------
 
-    def _read(self, value: jax.Array, to: Callable):
+    @staticmethod
+    def _read(value: jax.Array, to: Callable):
         """``to(value)``: one blocking device→host read."""
-        self.recorder.count("host_syncs")
-        with self.recorder.span("model.sync"):
+        with obs.DEFAULT.span("model.sync"):
             return to(value)
 
     def _sample_row(self, req, logits_row: jax.Array) -> int:
-        rec = self.recorder
-        with rec.span("model.sample", req.rid):
-            rec.count("rows_sampled")
+        with obs.DEFAULT.span("model.sample", req.rid):
             if not self._read(jnp.isfinite(logits_row).all(), bool):
                 # argmax and categorical would still return a token
                 raise FloatingPointError(
@@ -127,7 +123,7 @@ class StepLM:
                 out[i] = self._sample_row(req, self._logits.pop(req.rid)[0])
             else:
                 by_pos.setdefault(len(req.tokens) - 1, []).append(i)
-        rec = self.recorder
+        rec = obs.DEFAULT
         for pos, idxs in sorted(by_pos.items()):
             group = [reqs[i] for i in idxs]
             with rec.span("model.stack"):

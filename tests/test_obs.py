@@ -26,13 +26,25 @@ def _requests(n, seed):
         seed=int(rng.integers(0, 1 << 31))) for rid in range(n)]
 
 
-def _serve(reqs, recorder=None, model=None, layout=SMALL, **kw):
+def _serve(reqs, model=None, layout=SMALL, **kw):
     fd = ServeFrontDoor(model or HashLM(layout.row_bytes), layout,
-                        max_seq_len=24, recorder=recorder, **kw)
+                        max_seq_len=24, **kw)
     for r in reqs:
         fd.submit(r)
     fd.run()
     return fd
+
+
+@pytest.fixture
+def default_recorder():
+    """The module's recorder, which every layer reads: off and empty
+    before and after the test."""
+    rec = obs.DEFAULT
+    assert not rec.enabled
+    rec.clear()
+    yield rec
+    rec.enabled = False
+    rec.clear()
 
 
 def test_off_reads_no_clock_and_allocates_nothing(monkeypatch):
@@ -88,19 +100,21 @@ def test_on_nests_parents_keeps_rids_and_sums_counters():
     assert rec.events == [] and rec.counters == {}
 
 
-def test_front_door_serves_the_same_and_records_the_span_tree():
+def test_front_door_serves_the_same_and_records_the_span_tree(
+        default_recorder):
+    rec = default_recorder
     off = _requests(14, seed=2)
     on = _requests(14, seed=2)
     fd_off = _serve(off, max_running=6, low_watermark=1)
-    rec = obs.Recorder(enabled=True)
-    fd_on = _serve(on, recorder=rec, max_running=6, low_watermark=1)
+    assert rec.events == [] and rec.counters == {}
+    rec.enabled = True
+    fd_on = _serve(on, max_running=6, low_watermark=1)
+    rec.enabled = False
     assert fd_off.alloc.stats.preemptions > 0     # swaps happened
     assert [r.output for r in on] == [r.output for r in off]
     assert fd_on.metrics.cycles == fd_off.metrics.cycles
     assert fd_on.alloc.stats.preemptions == fd_off.alloc.stats.preemptions
     assert not hasattr(fd_on.metrics, "per_step")
-    assert obs.DEFAULT.events == [] and fd_off.recorder is obs.DEFAULT
-    assert fd_on.engine.recorder is rec
 
     ev = rec.events
     names = {e[0] for e in ev}
@@ -135,28 +149,29 @@ def tiny_lm():
         init_lm(jax.random.PRNGKey(1), cfg)
 
 
-def test_steplm_reads_the_device_twice_per_sampled_token(tiny_lm):
+def test_steplm_reads_the_device_twice_per_sampled_token(
+        tiny_lm, default_recorder):
     from repro.serve.sched import StepLM
     cfg, rcfg, params = tiny_lm
+    rec = default_recorder
     layout = KVLayout(n_pages=24, page_size=4, n_kv_heads=2, head_dim=4,
                       itemsize=4)
     outs = {}
     for on in (False, True):
-        rec = obs.Recorder(enabled=on)
+        rec.enabled = on
         reqs = [ServeRequest(rid=i, prompt=[3 + i, 5, 7, 9][:2 + i % 3],
                              max_new_tokens=3 + i, temperature=float(i % 2),
                              seed=i) for i in range(4)]
         model = StepLM(cfg, rcfg, params, max_len=32,
-                       row_bytes=layout.row_bytes, recorder=rec)
-        _serve(reqs, recorder=rec, model=model, layout=layout,
-               max_running=4)
+                       row_bytes=layout.row_bytes)
+        _serve(reqs, model=model, layout=layout, max_running=4)
+        rec.enabled = False
         outs[on] = [r.output for r in reqs]
     assert outs[True] == outs[False]
     tokens = sum(len(o) for o in outs[True])
-    assert rec.counters["rows_sampled"] == tokens
-    assert rec.counters["host_syncs"] == 2 * tokens
     ev = rec.events
     names = [e[0] for e in ev]
+    assert names.count("model.sample") == tokens
     assert names.count("model.sync") == 2 * tokens
     assert names.count("model.prefill") == 4
     for name, _, _, p, rid in ev:
