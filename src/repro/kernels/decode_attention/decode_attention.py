@@ -12,9 +12,11 @@ Layout: q (B, Hq, D) with Hq = Hkv * G; kv (B, Hkv, S, D).
 Grid: (B, Hkv, S / bk) — kv tiles stream sequentially per (batch, kv head),
 online softmax state in VMEM scratch.
 
-`kv_len` is a **traced scalar** (the current cache fill), so one compiled
-kernel serves the whole decode session — tiles beyond the fill are skipped
-via `pl.when` (no wasted KV bandwidth past the high-water mark).
+`kv_len` is **traced** (the current cache fill), so one compiled kernel
+serves the whole decode session: a scalar shared by every row, or one
+length per row (B,) when rows sit at different positions.  The lengths
+live in SMEM and grid row ``b`` reads its own; the compute of tiles
+beyond a row's fill is skipped via `pl.when` (their DMA is not).
 `window` (sliding-window decode) and `softcap` are static features.
 """
 
@@ -38,7 +40,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    scale: float, window: int, softcap: float,
                    bk: int, n_k: int, G: int, seq_k: int):
     ik = pl.program_id(2)
-    kv_len = len_ref[0, 0]
+    kv_len = len_ref[pl.program_id(0)]
 
     @pl.when(ik == 0)
     def _init():
@@ -94,7 +96,9 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                             scale: Optional[float] = None,
                             block_k: int = DEFAULT_BK,
                             interpret: bool = False) -> jax.Array:
-    """q (B, Hq, D); k/v (B, Hkv, S, D) → (B, Hq, D)."""
+    """q (B, Hq, D); k/v (B, Hkv, S, D) → (B, Hq, D).  ``kv_len``: a
+    scalar shared by every row, or (B,) — row ``b`` attends to
+    ``[0, kv_len[b])``."""
     B, Hq, D = q.shape
     _, Hkv, S, _ = k.shape
     if Hq % Hkv:
@@ -102,7 +106,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
     G = Hq // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     kv_len = S if kv_len is None else kv_len
-    len_arr = jnp.asarray(kv_len, jnp.int32).reshape(1, 1)
+    len_arr = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))
     bk = min(block_k, S)
     grid = (B, Hkv, pl.cdiv(S, bk))
 
@@ -116,7 +120,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, ik: (b, h, ik, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, ik: (b, h, ik, 0)),

@@ -13,7 +13,9 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                          kv_len: Optional[Union[int, jax.Array]] = None,
                          window: int = 0, softcap: float = 0.0,
                          scale: Optional[float] = None) -> jax.Array:
-    """q (B, Hq, D); k/v (B, Hkv, S, D) → (B, Hq, D)."""
+    """q (B, Hq, D); k/v (B, Hkv, S, D) → (B, Hq, D).  ``kv_len``: a
+    scalar shared by every row, or (B,) — row ``b`` attends to
+    ``[0, kv_len[b])``."""
     B, Hq, D = q.shape
     _, Hkv, S, _ = k.shape
     G = Hq // Hkv
@@ -28,10 +30,11 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
     cols = jnp.arange(S)
-    mask = cols < kv_len
+    lens = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (B,))[:, None]
+    mask = cols < lens                                  # (B, S)
     if window > 0:
-        mask = mask & (cols >= kv_len - window)
-    s = jnp.where(mask[None, None, None], s, -1e30)
+        mask = mask & (cols >= lens - window)
+    s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bhkd->bhgd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)

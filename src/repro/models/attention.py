@@ -11,6 +11,7 @@ prefill never materializes an (S, S) score matrix) — this is the path the
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -185,7 +186,8 @@ def attention_decode_step(p: Params, x: jax.Array, cache_k: jax.Array,
                           cfg: ArchConfig, rcfg: RunConfig, *, window: int,
                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode.  x (B, 1, d); cache (B, Hkv, S_max, dh);
-    pos — scalar int32 (current length).  Returns (out, new_k, new_v)."""
+    pos — int32 current length: a scalar shared by every row, or (B,),
+    one per row.  Returns (out, new_k, new_v)."""
     B = x.shape[0]
     dh = cfg.resolved_head_dim
     compute = jnp.bfloat16 if rcfg.dtype == "bfloat16" else jnp.float32
@@ -193,14 +195,19 @@ def attention_decode_step(p: Params, x: jax.Array, cache_k: jax.Array,
     q = _split_heads(dense(p["wq"], x, compute), cfg.n_heads, dh)
     k = _split_heads(dense(p["wk"], x, compute), cfg.n_kv_heads, dh)
     v = _split_heads(dense(p["wv"], x, compute), cfg.n_kv_heads, dh)
-    positions = jnp.full((1,), pos, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    positions = pos.reshape(-1, 1, 1)                  # (B or 1, 1, S=1)
     q = apply_rope(q, positions, cfg.rope_fraction, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
 
-    cache_k = jax.lax.dynamic_update_slice_in_dim(
-        cache_k, k.astype(cache_k.dtype), pos, axis=2)
-    cache_v = jax.lax.dynamic_update_slice_in_dim(
-        cache_v, v.astype(cache_v.dtype), pos, axis=2)
+    if pos.ndim:
+        # each row writes at its own position: a scatter of B slices,
+        # not a mask over the whole cache
+        write = jax.vmap(partial(jax.lax.dynamic_update_slice_in_dim, axis=1))
+    else:
+        write = partial(jax.lax.dynamic_update_slice_in_dim, axis=2)
+    cache_k = write(cache_k, k.astype(cache_k.dtype), pos)
+    cache_v = write(cache_v, v.astype(cache_v.dtype), pos)
 
     scale = cfg.query_scale if cfg.query_scale is not None \
         else 1.0 / math.sqrt(dh)
@@ -260,7 +267,12 @@ def attention_decode_step_ring(p: Params, x: jax.Array,
                                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Ring decode (full attention only).  Main cache holds [0, base);
     ring holds [base, pos]; slot = pos - base < R.  Returns
-    (out, new_ring_k, new_ring_v); the main cache is NOT touched."""
+    (out, new_ring_k, new_ring_v); the main cache is NOT touched.
+    ``pos`` is a scalar: every row shares one base."""
+    if jnp.ndim(pos):
+        raise ValueError(
+            "ring decode takes one scalar position for the whole batch "
+            f"(rows share one base), got pos of shape {jnp.shape(pos)}")
     B = x.shape[0]
     dh = cfg.resolved_head_dim
     compute = jnp.bfloat16 if rcfg.dtype == "bfloat16" else jnp.float32

@@ -148,6 +148,8 @@ def init_block_cache(batch: int, max_len: int, cfg: ArchConfig, kind: str,
 def block_decode_step(p: Params, x: jax.Array, cache: Dict[str, Any],
                       pos: jax.Array, cfg: ArchConfig, rcfg: RunConfig,
                       kind: str) -> Tuple[jax.Array, Dict[str, Any]]:
+    """One decode token through one block.  ``pos``: a scalar or (B,),
+    one per row (`attention_decode_step`); the SSM state needs none."""
     new_cache = dict(cache)
     h = rmsnorm(p["ln1"], x)
     window = _window_for(kind, cfg)

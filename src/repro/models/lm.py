@@ -200,8 +200,10 @@ def lm_decode_step(p: Params, caches: List[List[Dict[str, Any]]],
                    tokens: jax.Array, pos: jax.Array, cfg: ArchConfig,
                    rcfg: RunConfig
                    ) -> Tuple[jax.Array, List[List[Dict[str, Any]]]]:
-    """tokens (B, 1) current token; pos scalar — current cache fill.
-    Returns (logits (B, V) fp32, updated caches)."""
+    """tokens (B, 1) current token; pos — current cache fill, an int32
+    scalar shared by every row or a (B,) vector, one per row (ring
+    caches take a scalar only).  Returns (logits (B, V) fp32, updated
+    caches)."""
     compute = jnp.bfloat16 if rcfg.dtype == "bfloat16" else jnp.float32
     x = _embed_in(p, tokens, cfg, compute)
 

@@ -131,3 +131,76 @@ def test_ring_decode_matches_forward():
         if (t + 1) % R == 0:
             cache = flush_decode_caches(cache, jnp.int32(t + 1 - R))
     assert max(errs) < 1e-4, errs
+
+
+def test_ring_decode_refuses_per_row_positions():
+    cfg = reduced(get("qwen2.5-32b"))
+    params = init_lm(KEY, cfg)
+    cache = init_decode_cache(2, 32, cfg, jnp.float32, ring=4)
+    with pytest.raises(ValueError, match="one scalar position"):
+        lm_decode_step(params, cache, jnp.zeros((2, 1), jnp.int32),
+                       jnp.asarray([3, 5], jnp.int32), cfg, RCFG)
+
+
+# window 8 so that rows past position 8 decode through a live window
+@pytest.mark.parametrize("arch,window", [
+    ("chatglm3-6b", None), ("gemma2-2b", 8), ("mamba2-1.3b", None),
+    ("hymba-1.5b", 8)])
+def test_mixed_positions_match_rows_alone(arch, window):
+    """Rows at different positions through one `lm_decode_step` agree with
+    each row decoded alone; a scalar position is the all-equal vector."""
+    import dataclasses
+    cfg = reduced(get(arch))
+    if window is not None:
+        cfg = dataclasses.replace(cfg, window=window)
+    params = init_lm(KEY, cfg)
+    step = jax.jit(lm_decode_step, static_argnums=(4, 5))
+    S = 24
+    lengths = [1, 5, 12, 19]
+    rows = []
+    for i, n in enumerate(lengths):
+        toks = jax.random.randint(jax.random.fold_in(KEY, i), (1, n + 1), 0,
+                                  cfg.vocab_size)
+        _, cache = lm_prefill(params, toks[:, :n], cfg, RCFG, max_len=S)
+        rows.append((cache, toks[:, n:], n))
+
+    def stack(caches):
+        return jax.tree_util.tree_map(
+            lambda *a: jnp.concatenate(a, axis=1), *caches)
+
+    caches = stack([c for c, _, _ in rows])
+    cur = jnp.concatenate([t for _, t, _ in rows])
+    pos = jnp.asarray(lengths, jnp.int32)
+    logits, out = step(params, caches, cur, pos, cfg, RCFG)
+    for j, (cache, tok, n) in enumerate(rows):
+        lg1, out1 = step(params, cache, tok, jnp.int32(n), cfg,
+                                   RCFG)
+        np.testing.assert_allclose(logits[j], lg1[0], rtol=0, atol=1e-5)
+        for seg, seg1, seg_in in zip(out, out1, cache):
+            for c, c1, c_in in zip(seg, seg1, seg_in):
+                for name in c:
+                    row = jax.tree_util.tree_map(
+                        lambda a: np.asarray(a[:, j:j + 1]), c[name])
+                    if name not in ("k", "v"):       # recurrent state
+                        jax.tree_util.tree_map(
+                            lambda a, b: np.testing.assert_allclose(
+                                a, b, rtol=0, atol=1e-5), row, c1[name])
+                        continue
+                    # written at the row's own position, and nowhere
+                    # else: the rest is the row's cache as it came in
+                    np.testing.assert_allclose(
+                        row[:, :, :, n], np.asarray(c1[name])[:, :, :, n],
+                        rtol=0, atol=1e-5)
+                    assert np.array_equal(
+                        np.delete(row, n, axis=3),
+                        np.delete(np.asarray(c_in[name]), n, axis=3))
+
+    # a scalar position: the program as it was, and the same numbers as
+    # that position given once per row
+    same = jnp.full((len(lengths),), 12, jnp.int32)
+    lg_s, out_s = step(params, caches, cur, jnp.int32(12), cfg,
+                                 RCFG)
+    lg_v, out_v = step(params, caches, cur, same, cfg, RCFG)
+    assert np.array_equal(lg_s, lg_v)
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: bool(np.array_equal(a, b)), out_s, out_v))

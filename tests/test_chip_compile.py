@@ -68,13 +68,15 @@ def _compile(one_chip, fn, *shapes):
 @pytest.mark.parametrize("S", [4096, 1032])
 @pytest.mark.parametrize("window,softcap", [(0, 0.0), (4096, 50.0)],
                          ids=["global", "local"])
-def test_decode_attention(one_chip, S, window, softcap):
+@pytest.mark.parametrize("kv_len_shape", [(), (8,)],
+                         ids=["shared_len", "len_per_row"])
+def test_decode_attention(one_chip, S, window, softcap, kv_len_shape):
     def fn(q, k, v, kv_len):
         return decode_attention_pallas(q, k, v, kv_len=kv_len, window=window,
                                        softcap=softcap, scale=DH ** -0.5)
     _compile(one_chip, fn, ((8, HQ, DH), jnp.bfloat16),
              ((8, HKV, S, DH), jnp.bfloat16), ((8, HKV, S, DH), jnp.bfloat16),
-             ((), jnp.int32))
+             (kv_len_shape, jnp.int32))
 
 
 @pytest.mark.parametrize("Sq", [37, 512, 1000])

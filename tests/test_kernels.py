@@ -188,6 +188,34 @@ class TestDecodeAttention:
                                    window=case["win"])
         allclose(out, ref)
 
+    @pytest.mark.parametrize("win,cap", [(0, 30.0), (200, 30.0)],
+                             ids=["global", "windowed"])
+    def test_per_row_kv_len(self, win, cap):
+        """kv_len (B,): each row attends to its own length, from 1 to the
+        cache's capacity; a scalar is that length given to every row."""
+        from repro.kernels.decode_attention import (decode_attention,
+                                                    decode_attention_ref)
+        S = 640
+        q = arr((4, 8, 64), scale=0.5)
+        k, v = arr((4, 2, S, 64), scale=0.5), arr((4, 2, S, 64), scale=0.5)
+        kw = dict(window=win, softcap=cap, scale=0.125)
+        lens = jnp.asarray([1, 300, S, 77], jnp.int32)
+        out = decode_attention(q, k, v, kv_len=lens, block_k=256,
+                               backend="pallas", interpret=True, **kw)
+        allclose(out, decode_attention_ref(q, k, v, kv_len=lens, **kw))
+        for b in range(4):
+            allclose(out[b:b + 1], decode_attention_ref(
+                q[b:b + 1], k[b:b + 1], v[b:b + 1], kv_len=int(lens[b]),
+                **kw))
+        for backend in ("pallas", "xla"):
+            one = decode_attention(q, k, v, kv_len=300, block_k=256,
+                                   backend=backend, interpret=True, **kw)
+            every = decode_attention(q, k, v, kv_len=jnp.full((4,), 300,
+                                                              jnp.int32),
+                                     block_k=256, backend=backend,
+                                     interpret=True, **kw)
+            assert np.array_equal(np.asarray(one), np.asarray(every))
+
     def test_dynamic_kv_len(self):
         """kv_len may be a traced scalar (decode loops)."""
         from repro.kernels.decode_attention import (decode_attention,

@@ -303,6 +303,69 @@ class TestStepLM:
         solo = run(make_reqs(), max_running=1)
         assert batched == solo
 
+    @staticmethod
+    def _admitted(lengths):
+        """A tiny `StepLM` with one admitted request per prompt length,
+        each past its first (prefill) sample."""
+        jax = pytest.importorskip("jax")
+        from repro.configs import get
+        from repro.configs.base import RunConfig, reduced
+        from repro.models import init_lm
+        from repro.serve.sched import StepLM
+        cfg = reduced(get("gemma2-2b"), n_layers=2, d_model=64,
+                      n_heads=2, n_kv_heads=1, d_ff=128, vocab=64)
+        model = StepLM(cfg, RunConfig(kernels="xla", dtype="float32",
+                                      remat=False),
+                       init_lm(jax.random.PRNGKey(1), cfg), max_len=32,
+                       row_bytes=LAYOUT.row_bytes)
+        reqs = [ServeRequest(rid=i, prompt=[2 + i] * n, max_new_tokens=8)
+                for i, n in enumerate(lengths)]
+        empty = (np.zeros(0, np.uint8), np.zeros(0, np.uint8))
+
+        def step(group):
+            for r, t in zip(group, model.next_tokens(
+                    group, [empty] * len(group))):
+                r.tokens.append(t)
+                r.output.append(t)
+        for r in reqs:
+            r.tokens = list(r.prompt)
+            model.on_admit(r)
+        step(reqs)
+        return model, reqs, step
+
+    def test_rows_at_different_positions_share_one_decode_call(self):
+        from repro import obs
+        model, reqs, step = self._admitted([3, 6, 11])
+        rec = obs.DEFAULT
+        assert not rec.enabled
+        rec.clear()
+        rec.enabled = True
+        try:
+            step(reqs)
+        finally:
+            rec.enabled = False
+        names = [e[0] for e in rec.events]
+        rec.clear()
+        assert len({len(r.tokens) for r in reqs}) == 3
+        assert names.count("model.decode") == 1
+        assert names.count("model.stack") == 1
+        assert names.count("model.unstack") == names.count("model.sample") \
+            == 3
+
+    def test_subset_leaves_other_rows_caches_unchanged(self):
+        jax = pytest.importorskip("jax")
+        model, reqs, step = self._admitted([3, 6, 11, 4])
+        left = {r.rid: model._caches[r.rid] for r in reqs[2:]}
+        before = {rid: jax.tree_util.tree_map(np.asarray, c)
+                  for rid, c in left.items()}
+        moved = {r.rid: model._caches[r.rid] for r in reqs[:2]}
+        step(reqs[:2])
+        for rid, cache in left.items():
+            assert model._caches[rid] is cache
+            assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+                lambda a, b: bool(np.array_equal(a, b)), cache, before[rid]))
+        assert all(model._caches[rid] is not c for rid, c in moved.items())
+
     def test_nonfinite_logits_raise(self):
         jax = pytest.importorskip("jax")
         from repro.configs import get
