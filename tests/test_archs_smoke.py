@@ -2,6 +2,8 @@
 train step on CPU, asserting output shapes + finite values (deliverable f).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -149,7 +151,6 @@ def test_ring_decode_refuses_per_row_positions():
 def test_mixed_positions_match_rows_alone(arch, window):
     """Rows at different positions through one `lm_decode_step` agree with
     each row decoded alone; a scalar position is the all-equal vector."""
-    import dataclasses
     cfg = reduced(get(arch))
     if window is not None:
         cfg = dataclasses.replace(cfg, window=window)
@@ -173,8 +174,7 @@ def test_mixed_positions_match_rows_alone(arch, window):
     pos = jnp.asarray(lengths, jnp.int32)
     logits, out = step(params, caches, cur, pos, cfg, RCFG)
     for j, (cache, tok, n) in enumerate(rows):
-        lg1, out1 = step(params, cache, tok, jnp.int32(n), cfg,
-                                   RCFG)
+        lg1, out1 = step(params, cache, tok, jnp.int32(n), cfg, RCFG)
         np.testing.assert_allclose(logits[j], lg1[0], rtol=0, atol=1e-5)
         for seg, seg1, seg_in in zip(out, out1, cache):
             for c, c1, c_in in zip(seg, seg1, seg_in):
@@ -198,8 +198,7 @@ def test_mixed_positions_match_rows_alone(arch, window):
     # a scalar position: the program as it was, and the same numbers as
     # that position given once per row
     same = jnp.full((len(lengths),), 12, jnp.int32)
-    lg_s, out_s = step(params, caches, cur, jnp.int32(12), cfg,
-                                 RCFG)
+    lg_s, out_s = step(params, caches, cur, jnp.int32(12), cfg, RCFG)
     lg_v, out_v = step(params, caches, cur, same, cfg, RCFG)
     assert np.array_equal(lg_s, lg_v)
     assert jax.tree_util.tree_all(jax.tree_util.tree_map(
