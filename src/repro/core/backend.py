@@ -18,6 +18,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from numpy.lib.stride_tricks import as_strided
 
 from .descriptor import (CODE_PROTO, GENERATOR_PROTOCOLS, PROTO_CODE,
                          BackendOptions, DescriptorBatch, InitPattern,
@@ -339,7 +340,8 @@ def execute(bursts: Sequence[Transfer1D], mem: MemoryMap,
 # --------------------------------------------------------------------------
 
 #: payload bytes materialized per vectorized slice of one protocol group;
-#: bounds the int64 index scratch at a small multiple of this.
+#: bounds the gathered copy (and the generators' index scratch) at a
+#: small multiple of this.
 EXEC_CHUNK_BYTES = 16 << 20
 
 #: numeric Init pattern codes, so grouped stream generation never touches
@@ -392,8 +394,10 @@ def _length_bins(lens: np.ndarray):
     """Yield (L, rows) groups of equal burst length, zero-length dropped.
 
     Legalized streams cluster on very few distinct lengths (the protocol
-    cap plus tails), so binning turns ragged gather/scatter into dense 2-D
-    broadcast indexing — no `np.repeat` index materialization at all.
+    cap plus tails), so binning turns a ragged gather/scatter into a few
+    dense ones over rows of length L: a copy moves each burst as one row
+    of `_row_windows`, with no per-byte index; a generator fills a
+    (rows, L) block.
     """
     n = lens.shape[0]
     first = int(lens[0])
@@ -410,11 +414,28 @@ def _length_bins(lens: np.ndarray):
             yield length, order[bounds[k]:bounds[k + 1]]
 
 
+def _row_windows(buf: np.ndarray, length: int,
+                 writeable: bool = True) -> np.ndarray:
+    """`(buf.size - length + 1, length)` view of the 1-D space `buf` whose
+    row k is `buf[k:k + length]`: a burst of `length` bytes at any address
+    is one row.  Rows overlap in memory; no byte is copied."""
+    stride = buf.strides[0]
+    return as_strided(buf, (buf.shape[0] - length + 1, length),
+                      (stride, stride), writeable=writeable)
+
+
 def _exec_copy_group(src_buf: np.ndarray, dst_buf: np.ndarray,
                      sa: np.ndarray, da: np.ndarray, lens: np.ndarray,
                      instream, bins=None) -> None:
     """Grouped gather/scatter: every burst of one (src, dst) protocol pair
-    moved with two fancy-indexed array ops per length bin / chunk.
+    moved as one contiguous row per burst — per length bin L and chunk of
+    rows, a row gather `src[sa]` from the source's `_row_windows` view
+    into a temporary, then a row scatter into the destination's.  No
+    per-byte index is built.
+
+    The gather copies before the scatter writes, so same-space bursts read
+    the bytes as they were before the chunk; overlapping destinations
+    resolve in row order (last writer wins); bins run in ascending L.
 
     `bins` — precomputed `_length_bins(lens)` output (a captured plan's
     grouping); row indices are local to `sa`/`da`/`lens`.
@@ -422,11 +443,12 @@ def _exec_copy_group(src_buf: np.ndarray, dst_buf: np.ndarray,
     if instream is None:
         for length, rows in (bins if bins is not None
                              else _length_bins(lens)):
-            span = np.arange(length, dtype=np.int64)
+            src_rows = _row_windows(src_buf, length, writeable=False)
+            dst_rows = _row_windows(dst_buf, length)
             step = max(EXEC_CHUNK_BYTES // length, 1)
             for i in range(0, rows.shape[0], step):
                 r = rows[i:i + step]
-                dst_buf[da[r][:, None] + span] = src_buf[sa[r][:, None] + span]
+                dst_rows[da[r]] = src_rows[sa[r]]
         return
     # in-stream accelerator: per-burst chunks in row order (ragged path)
     for sl, pos, splits in _chunked(lens):
@@ -665,10 +687,12 @@ def execute_batch(batch: DescriptorBatch, mem: MemoryMap,
     (which remains the scalar oracle) — property tests assert the two are
     byte-identical.
 
-    Bursts are grouped by (src_protocol, dst_protocol); each group moves
-    through grouped gather/scatter with fancy indexing, ragged bursts
-    flattened via offset/length prefix sums and processed in
-    `EXEC_CHUNK_BYTES` slices so the index scratch stays bounded.
+    Bursts are grouped by (src_protocol, dst_protocol); a copy group moves
+    per length bin, each burst one contiguous row of a strided window view
+    of its space (`_exec_copy_group`: a row gather, then a row scatter, no
+    per-byte index), in `EXEC_CHUNK_BYTES` slices so the gathered copy
+    stays bounded.  With an in-stream accelerator the ragged bursts are
+    instead flattened via offset/length prefix sums.
     Generator (Init) sources produce their streams vectorized over the
     whole group on the `splitmix32` path.  The in-stream accelerator, when
     given, is applied per burst chunk, exactly as on the scalar path.
